@@ -1,4 +1,9 @@
-"""Shard-scaling sweep for the collective query pipeline (DESIGN.md §14).
+"""Shard-scaling sweep for the collective query pipeline (DESIGN.md §14),
+emulated on the host CPU: every timing it prints or saves is a CPU
+emulation of S devices, labelled so, and no chip measurement. It refuses
+to run where the parent's JAX platform is a TPU (its children would force
+the CPU and report those timings under the name of shard scaling); the
+four-chip check on a TPU host is ``chip_smoke.py --chips 4``.
 
 Each point S in {1, 2, 4, 8} runs in a fresh subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=S`` (device count must
@@ -85,7 +90,9 @@ def _child(s_shards: int, scale: str) -> dict:
     ei, ed, _ = search_sharded_emulated(skhi, Q, qlo, qhi, p)
     pow2 = s_shards >= 2 and (s_shards & (s_shards - 1)) == 0
     merges = ("halving", "allgather") if pow2 else ("allgather",)
-    out = {"S": s_shards, "build_s": round(build_s, 2), "merges": {}}
+    out = {"S": s_shards, "platform": jax.devices()[0].platform,
+           "devices": len(jax.devices()), "build_s": round(build_s, 2),
+           "merges": {}}
     for merge in merges:
         fn = make_sharded_search_fn(p, mesh, skhi=skhi,
                                     on_undersized="adjust", merge=merge)
@@ -130,11 +137,24 @@ def _best(point: dict) -> dict:
     return point["merges"].get("halving") or point["merges"]["allgather"]
 
 
+def refuse_on_tpu() -> None:
+    """Stop before spawning children when this process runs on a TPU."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            "bench_scale emulates S devices on the CPU and measures no chip; "
+            "on a TPU host run `python chip_smoke.py --chips 4`")
+
+
 def run(scale: str = "smoke", sweep=S_SWEEP, gate: float | None = None):
+    refuse_on_tpu()
     cfg = SCALE_CFG[scale]
     cores = os.cpu_count() or 1
     rows = [_spawn(s, scale) for s in sweep]
     for r in rows:
+        assert r["platform"] == "cpu", \
+            f"S={r['S']} child ran on {r['platform']}, not the CPU"
         for m, v in r["merges"].items():
             assert v["ids_equal_emulated"], \
                 f"S={r['S']} merge={m}: collective != emulated"
@@ -144,7 +164,8 @@ def run(scale: str = "smoke", sweep=S_SWEEP, gate: float | None = None):
         col = "qps_scaled" if cores < r["S"] else "qps_wall"
         b["speedup_vs_S1"] = round(b[col] / base[col], 2)
     payload = {
-        "scale": scale, "k": K, "host_parallelism": cores,
+        "platform": "cpu-emulated", "scale": scale, "k": K,
+        "host_parallelism": cores,
         "ratio_column": "qps_scaled (cores < S; see module docstring)"
                         if cores < max(sweep) else "qps_wall",
         "dataset": {k: cfg[k] for k in ("n", "d", "m", "B")},
@@ -165,7 +186,9 @@ def csv_lines(payload):
     out = []
     for r in payload["rows"]:
         for m, v in r["merges"].items():
-            out.append(f"scale_S{r['S']}_{m},{v['t_wall_ms'] * 1e3:.0f},"
+            out.append(f"scale_cpu_emulated_S{r['S']}_{m},"
+                       f"{v['t_wall_ms'] * 1e3:.0f},"
+                       f"platform={r['platform']}x{r['devices']};"
                        f"qps_wall={v['qps_wall']};"
                        f"qps_scaled={v['qps_scaled']};"
                        f"bytes={v['merge_bytes_per_device']}")

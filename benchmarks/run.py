@@ -57,6 +57,7 @@ def main(argv=None) -> None:
     if want("load"):
         lines += load_bench.csv_lines(load_bench.run(args.scale))
     if want("scale"):
+        # CPU emulation of S devices; refuses on a TPU (bench_scale docs)
         from . import bench_scale
         lines += bench_scale.csv_lines(bench_scale.run(args.scale))
 

@@ -2,11 +2,10 @@
 
 This is the accelerator formulation of ``hnsw.build_graphs_bulk``: per tree
 node, the exact top-``ef_b`` in-node candidate list of every member comes
-from a blocked all-pairs distance computation (one MXU matmul per tile —
-``kernels/l2dist`` on TPU, a ``dot_general`` with the same expansion
-formula elsewhere), and the HNSW RNG pruning rule runs as a *vectorized
-masked scan*: a ``lax.fori_loop`` over the candidate axis that carries a
-kept-neighbor buffer per row and applies the shielding test
+from a blocked all-pairs distance computation (a ``dot_general`` in the
+numpy builder's expansion-formula order), and the HNSW RNG pruning rule
+runs as a *vectorized masked scan*: a ``lax.fori_loop`` over the candidate
+axis that carries a kept mask per row and applies the shielding test
 ``d(e, r) < d(e, o)`` to all rows of a node (or a whole group of nodes)
 simultaneously. The output lands under the exact ``(H, n, M)`` int32
 ``nbrs`` contract of the numpy builders, bit-identical to
@@ -33,6 +32,7 @@ keeps f32 so device and numpy builders agree bit-for-bit.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Optional
 
 import jax
@@ -43,26 +43,30 @@ from .tree import PartitionTree
 
 __all__ = ["build_graphs_device"]
 
+# cap on a large node's (row block, C) f32 distance block: 512 rows at the
+# root of a 1M-row shard
+_BLOCK_BYTES = 1 << 31
+
 
 def _next_pow2(x: int) -> int:
     return 1 << max(x - 1, 0).bit_length()
 
 
-def _pairwise_d2(rows: jax.Array, pool: jax.Array, *, dist: str,
-                 interpret: Optional[bool], mm_dtype: Optional[str]):
+def _pow2_floor(x: int) -> int:
+    return 1 << (max(x, 1).bit_length() - 1)
+
+
+def _pairwise_d2(rows: jax.Array, pool: jax.Array, *,
+                 mm_dtype: Optional[str]):
     """Squared L2 rows (R, d) x pool (C, d) -> (R, C) f32.
 
-    The jnp path mirrors the numpy builder's expansion-formula evaluation
-    order ``(colsq - 2 * rows @ pool.T) + rowsq`` so the two builders'
-    decision comparisons agree to the last bit wherever the backends'
-    matmuls do; the pallas path routes the same shape through the
-    MXU-tiled ``l2dist`` kernel."""
+    Mirrors the numpy builder's expansion-formula evaluation order
+    ``(colsq - 2 * rows @ pool.T) + rowsq`` so the two builders' decision
+    comparisons agree to the last bit wherever the backends' matmuls do.
+    (On a TPU v5e this ``dot_general`` beat the Pallas ``l2dist`` kernel at
+    every build shape measured, by 1.06-9.5x.)"""
     rc = rows.astype(mm_dtype) if mm_dtype else rows
     pc = pool.astype(mm_dtype) if mm_dtype else pool
-    if dist == "pallas":
-        from ..kernels.ops import l2dist
-
-        return l2dist(rc, pc, interpret=interpret)
     rs = jnp.sum(rows * rows, axis=-1)
     ps = jnp.sum(pool * pool, axis=-1)
     mm = jax.lax.dot_general(rc, pc, (((1,), (1,)), ((), ())),
@@ -71,8 +75,8 @@ def _pairwise_d2(rows: jax.Array, pool: jax.Array, *, dist: str,
 
 
 def _node_core(pool: jax.Array, rows: jax.Array, row_pos: jax.Array,
-               count: jax.Array, *, K: int, M_eff: int, dist: str,
-               interpret: Optional[bool], mm_dtype: Optional[str]):
+               count: jax.Array, *, K: int, M_eff: int,
+               mm_dtype: Optional[str]):
     """Top-K + masked RNG prune for ``rows`` (a block of one node's members).
 
     pool:    (C, d) the node's member vectors, zero-padded past ``count``.
@@ -85,69 +89,94 @@ def _node_core(pool: jax.Array, rows: jax.Array, row_pos: jax.Array,
     C, d = pool.shape
     R = rows.shape[0]
     col_valid = jnp.arange(C) < count
-    d2 = _pairwise_d2(rows, pool, dist=dist, interpret=interpret,
-                      mm_dtype=mm_dtype)
+    d2 = _pairwise_d2(rows, pool, mm_dtype=mm_dtype)
     d2 = jnp.where(col_valid[None, :], d2, jnp.inf)
     neg, idx = jax.lax.top_k(-d2, K)          # ascending distance, K slots
     dd = -neg
 
-    ar = jnp.arange(R)
-    slot_ids = jnp.arange(M_eff)
+    # d(e_a, e_b) between every row's K candidates, from one batched Gram
+    # product: the prune then reads an (R, K, K) block once, where
+    # shielding tests against a kept-vector buffer would re-read (R, M_eff,
+    # d) at each of the K steps (memory-bound on a TPU)
+    cv = pool[idx]                                         # (R, K, d)
+    sq = jnp.sum(cv * cv, axis=-1)
+    gram = jnp.einsum("rkd,rjd->rkj", cv, cv,
+                      precision=jax.lax.Precision.HIGHEST)
+    cc = (sq[:, :, None] - 2.0 * gram) + sq[:, None, :]   # (R, K, K)
 
     def body(j, st):
-        kept_loc, kept_vec, cnt = st
+        kept, cnt = st                                     # kept (R, K)
         e_loc = jax.lax.dynamic_index_in_dim(idx, j, 1, keepdims=False)
         e_d = jax.lax.dynamic_index_in_dim(dd, j, 1, keepdims=False)
-        ev = pool[e_loc]                                   # (R, d)
-        diff = kept_vec - ev[:, None, :]
-        d_er = jnp.sum(diff * diff, axis=-1)               # (R, M_eff)
-        live = slot_ids[None, :] < cnt[:, None]
-        shielded = ((d_er < e_d[:, None]) & live).any(axis=1)
+        d_er = jax.lax.dynamic_index_in_dim(cc, j, 1, keepdims=False)
+        shielded = (kept & (d_er < e_d[:, None])).any(axis=1)
         accept = (jnp.isfinite(e_d) & (e_loc != row_pos)
                   & ~shielded & (cnt < M_eff))
-        slot = jnp.where(accept, cnt, M_eff)               # M_eff = dropped
-        kept_loc = kept_loc.at[ar, slot].set(
-            e_loc.astype(jnp.int32), mode="drop")
-        kept_vec = kept_vec.at[ar, slot].set(ev, mode="drop")
-        return kept_loc, kept_vec, cnt + accept.astype(jnp.int32)
+        kept = jax.lax.dynamic_update_index_in_dim(kept, accept, j, 1)
+        return kept, cnt + accept.astype(jnp.int32)
 
-    kept0 = (jnp.full((R, M_eff), -1, jnp.int32),
-             jnp.zeros((R, M_eff, d), pool.dtype),
-             jnp.zeros((R,), jnp.int32))
-    kept_loc, _, _ = jax.lax.fori_loop(0, K, body, kept0)
+    kept, _ = jax.lax.fori_loop(
+        0, K, body, (jnp.zeros((R, K), bool), jnp.zeros((R,), jnp.int32)))
+    # accepted candidates keep their scan order in slots 0 .. cnt-1
+    slot = jnp.where(kept, jnp.cumsum(kept, axis=1) - 1, M_eff)
+    kept_loc = jnp.full((R, M_eff), -1, jnp.int32).at[
+        jnp.arange(R)[:, None], slot].set(idx.astype(jnp.int32), mode="drop")
     return kept_loc
 
 
+def _node_pools(vo: jax.Array, starts: jax.Array, counts: jax.Array,
+                C: int) -> jax.Array:
+    """(G, C, d) member vectors of G nodes, zero-padded past each count.
+    ``vo`` holds the vectors in tree order, where every node's members are
+    the one contiguous slice ``[start, start + count)``."""
+    pos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)
+    pools = vo[jnp.minimum(pos, vo.shape[0] - 1)]
+    live = jnp.arange(C)[None, :] < counts[:, None]
+    return jnp.where(live[..., None], pools, jnp.zeros((), vo.dtype))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "K", "M_eff", "dist", "interpret", "mm_dtype"))
-def _build_group(pools, counts, *, K, M_eff, dist, interpret, mm_dtype):
-    """vmap of ``_node_core`` over a size-class group: pools (G, C, d)."""
-    C = pools.shape[1]
+    "C", "K", "M_eff", "mm_dtype"))
+def _build_group(vo, starts, counts, *, C, K, M_eff, mm_dtype):
+    """vmap of ``_node_core`` over a size-class group of G nodes."""
     pos = jnp.arange(C, dtype=jnp.int32)
 
     def one(pool, count):
         return _node_core(pool, pool, pos, count, K=K, M_eff=M_eff,
-                          dist=dist, interpret=interpret, mm_dtype=mm_dtype)
+                          mm_dtype=mm_dtype)
 
-    return jax.vmap(one)(pools, counts)
+    return jax.vmap(one)(_node_pools(vo, starts, counts, C), counts)
+
+
+_node_pool = jax.jit(lambda vo, start, count, C: _node_pools(
+    vo, start[None], count[None], C)[0], static_argnums=3)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "K", "M_eff", "dist", "interpret", "mm_dtype"))
-def _build_rows(pool, rows, row_pos, count, *, K, M_eff, dist, interpret,
-                mm_dtype):
-    """Row-blocked single-node path for nodes above ``large_node``."""
+    "RB", "K", "M_eff", "mm_dtype"))
+def _build_rows(pool, s, count, *, RB, K, M_eff, mm_dtype):
+    """Row-blocked single-node path for nodes above ``large_node``: the
+    ``RB`` pool rows from ``s`` against the whole pool."""
+    rows = jax.lax.dynamic_slice_in_dim(pool, s, RB)
+    row_pos = s + jnp.arange(RB, dtype=jnp.int32)
     return _node_core(pool, rows, row_pos, count, K=K, M_eff=M_eff,
-                      dist=dist, interpret=interpret, mm_dtype=mm_dtype)
+                      mm_dtype=mm_dtype)
 
 
-def _scatter_rows(nbrs: np.ndarray, lvl: int, node_objs: np.ndarray,
-                  row_objs: np.ndarray, kept_loc: np.ndarray,
-                  M_eff: int) -> None:
-    """Map pool-local kept indices (into ``node_objs``) to global ids and
-    write the (row_objs, M_eff) block of the (H, n, M) planes."""
-    gid = np.where(kept_loc >= 0, node_objs[kept_loc], -1).astype(np.int32)
-    nbrs[lvl, row_objs, :M_eff] = gid
+def _scatter(nbrs: np.ndarray, order: np.ndarray, levels: np.ndarray,
+             starts: np.ndarray, counts: np.ndarray, first: int,
+             kept: np.ndarray) -> None:
+    """Write the adjacency rows of G nodes into the (H, n, M) planes.
+    ``kept`` (G, R, M_eff) holds node-local kept positions (-1 padded) for
+    the members ``first .. first + R`` of each node; rows past a node's
+    count are ignored."""
+    G, R, M_eff = kept.shape
+    member = first + np.arange(R)
+    live = member[None, :] < counts[:, None]                     # (G, R)
+    g, r = np.nonzero(live)
+    loc = kept[g, r]                                             # (V, M_eff)
+    gid = np.where(loc >= 0, order[starts[g, None] + loc], -1)
+    nbrs[levels[g], order[starts[g] + member[r]], :M_eff] = gid
 
 
 def build_graphs_device(
@@ -159,86 +188,82 @@ def build_graphs_device(
     row_block: int = 2048,
     large_node: int = 4096,
     group_row_cap: int = 4096,
-    dist: str = "auto",
     matmul_dtype: Optional[str] = None,
-    interpret: Optional[bool] = None,
     verbose: bool = False,
 ) -> np.ndarray:
     """Device-native bulk build: returns ``nbrs`` (H, n, M) int32, -1 padded.
 
-    ``dist``: "auto" (pallas on TPU, jnp elsewhere) | "jnp" | "pallas".
+    The vectors go to the device once, in tree order, so every node's
+    pool is gathered there from its (start, count) slice; only node
+    offsets go in and kept neighbor ids come back. Results are fetched one
+    program behind the dispatch, so the host's scatter of one block
+    overlaps the device's work on the next.
+
+    A large node's row block shrinks below ``row_block`` where its
+    (row block, C) f32 distance block would pass ``_BLOCK_BYTES``.
     ``matmul_dtype``: e.g. "bfloat16" for bf16 candidate matmuls (f32
     accumulation); None keeps full f32 (bit-parity with the numpy bulk
-    builder on the jnp path).
+    builder).
     """
     ef_b = ef_b or max(M, 2 * M)  # same default as build_graphs_bulk
-    if dist == "auto":
-        dist = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    if dist not in ("jnp", "pallas"):
-        raise ValueError(f"dist must be auto|jnp|pallas, got {dist!r}")
     mm = str(jnp.dtype(matmul_dtype).name) if matmul_dtype else None
 
     n, d = vecs.shape
     H = tree.height
     nbrs = np.full((H, n, M), -1, dtype=np.int32)
-    vecs = np.ascontiguousarray(vecs, dtype=np.float32)
+    order = np.asarray(tree.order)
+    vo = jnp.asarray(np.asarray(vecs, dtype=np.float32)[order])
 
-    groups: dict[int, list] = {}
-    big: list = []
-    for p in range(tree.num_nodes):
-        objs = tree.node_objects(p)
-        c = len(objs)
-        if c <= 1:
-            continue
-        C = max(8, _next_pow2(c))
-        item = (int(tree.level[p]), objs)
-        (big if C > large_node else groups.setdefault(C, [])).append(item)
+    pending: list = []
+    t0 = time.perf_counter()
 
+    def drain(keep: int) -> None:
+        while len(pending) > keep:
+            out, args = pending.pop(0)
+            _scatter(nbrs, order, *args, np.asarray(out))
+
+    multi = np.nonzero(tree.count > 1)[0]
+    C_of = np.maximum(8, 1 << np.ceil(np.log2(tree.count[multi]))
+                      .astype(np.int64))
     # small/medium nodes: one vmapped program per size class
-    for C in sorted(groups):
-        items = groups[C]
+    for C in sorted(set(C_of[C_of <= large_node].tolist())):
+        nodes = multi[C_of == C]
         K = min(ef_b + 1, C)
         M_eff = min(M, K - 1)
         Gc = max(1, group_row_cap // C)
-        for s in range(0, len(items), Gc):
-            chunk = items[s : s + Gc]
-            pools = np.zeros((Gc, C, d), np.float32)
+        for s in range(0, len(nodes), Gc):
+            chunk = nodes[s : s + Gc]
+            starts = np.zeros((Gc,), np.int32)
             counts = np.zeros((Gc,), np.int32)
-            for g, (_, objs) in enumerate(chunk):
-                pools[g, : len(objs)] = vecs[objs]
-                counts[g] = len(objs)
-            kept = np.asarray(_build_group(
-                jnp.asarray(pools), jnp.asarray(counts), K=K, M_eff=M_eff,
-                dist=dist, interpret=interpret, mm_dtype=mm))
-            for g, (lvl, objs) in enumerate(chunk):
-                _scatter_rows(nbrs, lvl, objs, objs, kept[g, : len(objs)],
-                              M_eff)
+            starts[: len(chunk)] = tree.start[chunk]
+            counts[: len(chunk)] = tree.count[chunk]
+            out = _build_group(vo, jnp.asarray(starts), jnp.asarray(counts),
+                               C=C, K=K, M_eff=M_eff, mm_dtype=mm)
+            pending.append((out, (tree.level[chunk], starts, counts, 0)))
+            drain(1)
         if verbose:
-            print(f"[build_device] class C={C}: {len(items)} nodes "
-                  f"(K={K}, M_eff={M_eff})", flush=True)
+            print(f"[build_device] class C={C}: {len(nodes)} nodes "
+                  f"(K={K}, M_eff={M_eff}) dispatched by "
+                  f"{time.perf_counter() - t0:.1f}s", flush=True)
 
-    # large nodes: row-blocked, distance block (row_block, C)
-    for lvl, objs in big:
-        c = len(objs)
+    # large nodes: row-blocked, distance block (RB, C)
+    for p in multi[C_of > large_node]:
+        c = int(tree.count[p])
         C = _next_pow2(c)
         K = min(ef_b + 1, C)
         M_eff = min(M, K - 1)
-        pool = np.zeros((C, d), np.float32)
-        pool[:c] = vecs[objs]
-        pj = jnp.asarray(pool)
-        cnt = jnp.asarray(c, jnp.int32)
-        RB = min(row_block, C)
+        RB = min(row_block, C, max(8, _pow2_floor(_BLOCK_BYTES // (4 * C))))
+        pool = _node_pool(vo, jnp.int32(tree.start[p]), jnp.int32(c), C)
+        meta = (tree.level[p : p + 1], tree.start[p : p + 1],
+                tree.count[p : p + 1])
         for s in range(0, c, RB):
-            take = min(RB, c - s)
-            rows = np.zeros((RB, d), np.float32)
-            rows[:take] = pool[s : s + take]
-            row_pos = np.arange(s, s + RB, dtype=np.int32)
-            kept = np.asarray(_build_rows(
-                pj, jnp.asarray(rows), jnp.asarray(row_pos), cnt, K=K,
-                M_eff=M_eff, dist=dist, interpret=interpret, mm_dtype=mm))
-            _scatter_rows(nbrs, lvl, objs, objs[s : s + take], kept[:take],
-                          M_eff)
+            out = _build_rows(pool, jnp.int32(s), jnp.int32(c), RB=RB, K=K,
+                              M_eff=M_eff, mm_dtype=mm)
+            pending.append((out[None], (*meta, s)))
+            drain(1)
+        del pool
         if verbose:
-            print(f"[build_device] large node level {lvl} size {c} done",
-                  flush=True)
+            print(f"[build_device] large node level {tree.level[p]} size {c}"
+                  f" dispatched by {time.perf_counter() - t0:.1f}s", flush=True)
+    drain(0)
     return nbrs

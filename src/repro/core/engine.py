@@ -171,12 +171,21 @@ class DeviceIndex:
         return self.nbrs.shape[1]
 
 
+# device rows pad to a multiple of 32, whole (8, 128) 32-bit HBM tiles for
+# f32 (8 rows), bf16 (16) and int8 (32) alike: the gather kernels DMA a
+# candidate's whole tile (kernels/blocks.py), so an aligned corpus is never
+# copied inside the hop loop
+_ROW_ALIGN = 32
+
+
 def device_put_index(index: KHIIndex, *, pad_nodes: Optional[int] = None,
                      pad_n: Optional[int] = None,
                      pad_height: Optional[int] = None,
                      vec_dtype=None, quant: str = "none") -> DeviceIndex:
     """Flatten a host KHIIndex into device arrays (optionally padded so that
-    multiple shards can be stacked into one leading-axis array).
+    multiple shards can be stacked into one leading-axis array). Rows
+    always pad up to a multiple of ``_ROW_ALIGN``; pad rows carry +inf
+    attrs and no graph edges, and the Planner NaN-masks them for scans.
 
     ``vec_dtype=jnp.bfloat16`` stores corpus vectors in bf16 (distances still
     accumulate in f32) — halves the dominant HBM term of the search engine
@@ -187,7 +196,7 @@ def device_put_index(index: KHIIndex, *, pad_nodes: Optional[int] = None,
     P = t.num_nodes
     nbrs = np.ascontiguousarray(np.transpose(index.nbrs, (1, 0, 2)))  # (n,H,M)
 
-    pn = pad_n or n
+    pn = -(-(pad_n or n) // _ROW_ALIGN) * _ROW_ALIGN
     pP = pad_nodes or P
     pH = pad_height or H
 
@@ -1192,6 +1201,9 @@ class Planner:
         self._scan_attrs = jnp.where(jnp.asarray(valid)[..., None],
                                      di.attrs, jnp.nan)
 
+        # name -> (jitted program, its leading index arguments), for
+        # compiled_text
+        self._programs: dict = {}
         self._graph_fn = (self._build_graph_fn()
                           if p.strategy in ("graph", "auto", "hybrid")
                           else None)
@@ -1358,6 +1370,7 @@ class Planner:
                                        exact_scorer=exact)
                 return jax.vmap(lambda qq, lo, hi: fn(di, qq, lo, hi))(
                     q, qlo, qhi)
+            self._programs["graph"] = (graph, lambda: (self.index,))
             return lambda q, qlo, qhi: graph(self.index, q, qlo, qhi)
 
         from .sharded import _merge_topk, _shard_search
@@ -1372,6 +1385,7 @@ class Planner:
             mi, md = _merge_topk(gids, dists, p.k)
             return mi, md, jnp.max(hops, axis=0)
 
+        self._programs["graph"] = (graph_sharded, lambda: (self.index,))
         return lambda q, qlo, qhi: graph_sharded(self.index, q, qlo, qhi)
 
     def _build_scan_fn(self):
@@ -1388,6 +1402,8 @@ class Planner:
             @jax.jit
             def scan(di, attrs_nan, q, qlo, qhi):
                 return scan_one(di, None, attrs_nan, q, qlo, qhi)
+            self._programs["scan"] = (
+                scan, lambda: (self.index, self._scan_attrs))
             return lambda q, qlo, qhi: scan(self.index, self._scan_attrs,
                                             q, qlo, qhi)
 
@@ -1404,8 +1420,20 @@ class Planner:
                 gd.append(jnp.where(gids >= 0, dd, jnp.inf))
             return _merge_topk(jnp.stack(gi), jnp.stack(gd), p.k)
 
+        self._programs["scan"] = (
+            scan_sharded, lambda: (self.index, self._scan_attrs))
         return lambda q, qlo, qhi: scan_sharded(self.index, self._scan_attrs,
                                                 q, qlo, qhi)
+
+    def compiled_text(self, batch: int) -> dict:
+        """Compiled HLO text of each whole-batch device program ("graph",
+        "scan") at ``batch`` lanes — e.g. to check that the Pallas kernels
+        lowered to Mosaic custom calls and not to the interpreter."""
+        di = self.index.di if self._sharded else self.index
+        q = jax.ShapeDtypeStruct((batch, di.vecs.shape[-1]), jnp.float32)
+        box = jax.ShapeDtypeStruct((batch, di.attrs.shape[-1]), jnp.float32)
+        return {name: fn.lower(*args(), q, box, box).compile().as_text()
+                for name, (fn, args) in self._programs.items()}
 
     # ------------------------------------------------- hybrid window pass
     def _build_windows(self, small_nodes: list, idx: np.ndarray, bp: int):

@@ -15,10 +15,13 @@ entry vectors** (pinned by tests/test_router.py):
   * ``route_level_sync`` — the production router: a fixed
     ``lax.fori_loop`` over tree **levels** (height is O(log n), Lemma 1)
     with a per-query fixed-width frontier of (node, D-bitmask) pairs.
-    Every level processes its whole frontier at once — entry scans are
-    batched per level as one ``(F, scan_budget)`` window gather instead
-    of one scan per pop — and the loop trip count is the tree height,
-    identical for every lane of the batch.
+    Every level classifies its whole frontier at once, then entry-scans
+    its scanned nodes in DFS-rank order, ``c_e`` at a time and
+    ``_SCAN_STEP`` objects per node per step, until no further node can
+    enter the answer — and the level loop's trip count is the tree
+    height, identical for every lane of the batch. (A whole-frontier
+    ``(F, scan_budget)`` gather grows with n twice over: at one 1M-row
+    shard F is ~4.3e5 nodes and scan_budget ~5e4 rows.)
 
 Why the two return the same entries: the DFS collects entries in pop
 order (right child pushed last, popped first — right-first pre-order)
@@ -53,8 +56,8 @@ Caveat: the DFS early-stops after ``c_e`` entries, so *its* sum covers
 only the visited prefix of the antichain and is NOT a bound — the
 planner therefore requires ``router="level"`` (the sweep always runs
 all levels). ``route_level_card`` is the estimate-only form: same
-traversal, no entry scans (it skips the per-level ``(F, scan_budget)``
-window gather, the expensive part of routing).
+traversal, no entry scans (it skips the per-level entry-scan gathers,
+the expensive part of routing).
 """
 
 from __future__ import annotations
@@ -75,6 +78,11 @@ __all__ = ["ROUTERS", "resolve_router", "route_dfs", "route_level_sync",
 ROUTERS = ("level", "dfs")
 
 _I32_MAX = np.iinfo(np.int32).max
+# objects read per node per entry-scan step of the level router: a node's
+# first in-box object is usually among its first few, and scan_budget (the
+# largest scannable node, ~5e4 rows at a 1M-row shard) only bounds how far
+# the scan may go
+_SCAN_STEP = 128
 
 
 def _root_D0(di, qlo, qhi, m: int) -> jax.Array:
@@ -189,6 +197,12 @@ def _require_frontier(F: int) -> None:
             "arbitrary fixed width would silently drop router branches.")
 
 
+def _frontier0(di, qlo, qhi, m: int, F: int):
+    """Width-F frontier holding the root and its seed D."""
+    return (jnp.full((F,), -1, jnp.int32).at[0].set(di.root),
+            jnp.zeros((F,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m)))
+
+
 def _frontier_step(di, qlo, qhi, F: int, full: int, fnode, fD):
     """One level of the sweep, shared by the entry router and the
     card-only estimator: classify the frontier (scanned antichain nodes
@@ -245,58 +259,88 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
     H = di.nbrs.shape[1]          # tree levels == path height (tree.py)
     n = di.order.shape[0]
     SB = p.scan_budget
-    order_pad = jnp.pad(di.order, (0, SB))
-    scan_lane = jnp.arange(SB)
+    W = min(SB, _SCAN_STEP)
+    order_pad = jnp.pad(di.order, (0, W))
+    lane = jnp.arange(W)
 
-    fnode0 = jnp.full((F,), -1, jnp.int32).at[0].set(di.root)
-    fD0 = jnp.zeros((F,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m))
-    keys0 = jnp.full((p.c_e,), _I32_MAX, jnp.int32)
-    ents0 = jnp.full((p.c_e,), -1, jnp.int32)
+    def first_in_box(sj, cj):
+        """(T,) first object of each node, in DFS order, that lies in the
+        box (-1: none among its first min(count, scan_budget)) — read W
+        objects at a time until every node has its hit or runs out."""
+        lim = jnp.minimum(cj, SB)
+
+        def more(c):
+            off, e = c
+            return jnp.any((e < 0) & (off < lim))
+
+        def step(c):
+            off, e = c
+            pos = off + lane                                # (W,)
+            win = order_pad[sj[:, None] + pos[None, :]]     # (T, W)
+            a = di.attrs[win]                               # (T, W, m)
+            ok = ((pos[None, :] < lim[:, None])
+                  & jnp.all((a >= qlo) & (a <= qhi), axis=-1))
+            hit = jnp.take_along_axis(
+                win, jnp.argmax(ok, axis=1)[:, None], axis=1)[:, 0]
+            return off + W, jnp.where((e < 0) & ok.any(axis=1), hit, e)
+
+        return jax.lax.while_loop(
+            more, step, (jnp.int32(0), jnp.full(sj.shape, -1, jnp.int32)))[1]
+
+    T = min(F, p.c_e)             # nodes entry-scanned per chunk
 
     def level(_lvl, st):
         fnode, fD, keys, ents, card = st
         node, do_scan, fnode, fD = _frontier_step(di, qlo, qhi, F, full,
                                                   fnode, fD)
-        card = card + jnp.sum(jnp.where(do_scan, di.count[node], 0))
+        s = di.start[node]
+        cnt = di.count[node]
+        card = card + jnp.sum(jnp.where(do_scan, cnt, 0))
+        # DFS-rank keys of the level's scanned nodes: right-first
+        # pre-order over the scanned antichain == descending range end
+        # (module docstring)
+        cand = jnp.where(do_scan, n - (s + cnt), _I32_MAX)
 
-        # ---- batched entry scan: the whole level's windows in one gather
-        s = di.start[node]                              # (F,)
-        win = order_pad[s[:, None] + scan_lane[None, :]]  # (F, SB)
-        in_node = scan_lane[None, :] < di.count[node][:, None]
-        a = di.attrs[win]                               # (F, SB, m)
-        ok = in_node & jnp.all((a >= qlo) & (a <= qhi), axis=-1)
-        hit = jnp.argmax(ok, axis=1)
-        e = jnp.take_along_axis(win, hit[:, None], axis=1)[:, 0]
-        e = jnp.where(do_scan & ok.any(axis=1), e, -1).astype(jnp.int32)
+        # ---- entry scans in key order, T nodes at a time, while a
+        # scanned node could still enter the running c_e smallest keys:
+        # the same entries as scanning the whole level at once
+        def pending(c):
+            cand, keys, _ = c
+            return jnp.any(cand < keys[-1])
 
-        # ---- DFS-rank keys: right-first pre-order over the scanned
-        # antichain == descending range end (module docstring)
-        key = jnp.where(e >= 0, n - (s + di.count[node]), _I32_MAX)
-        allk = jnp.concatenate([keys, key.astype(jnp.int32)])
-        alle = jnp.concatenate([ents, e])
-        srt = jnp.argsort(allk, stable=True)[: p.c_e]
-        keys, ents = allk[srt], alle[srt]
+        def scan_chunk(c):
+            cand, keys, ents = c
+            neg, j = jax.lax.top_k(-cand, T)
+            live = -neg < keys[-1]
+            e = first_in_box(s[j], jnp.where(live, cnt[j], 0))
+            key = jnp.where(e >= 0, -neg, _I32_MAX)
+            allk = jnp.concatenate([keys, key])
+            alle = jnp.concatenate([ents, e])
+            srt = jnp.argsort(allk, stable=True)[: p.c_e]
+            return cand.at[j].set(_I32_MAX), allk[srt], alle[srt]
+
+        _, keys, ents = jax.lax.while_loop(pending, scan_chunk,
+                                           (cand, keys, ents))
         return fnode, fD, keys, ents, card
 
-    st = jax.lax.fori_loop(0, H, level,
-                           (fnode0, fD0, keys0, ents0, jnp.int32(0)))
+    st = jax.lax.fori_loop(
+        0, H, level, (*_frontier0(di, qlo, qhi, m, F),
+                      jnp.full((p.c_e,), _I32_MAX, jnp.int32),
+                      jnp.full((p.c_e,), -1, jnp.int32), jnp.int32(0)))
     return st[3], st[4]
 
 
 def route_level_card(di, qlo: jax.Array, qhi: jax.Array, p) -> jax.Array:
     """Estimate-only sweep: the () int32 in-range cardinality bound of
     ``route_level_sync`` without the entry scans — same traversal, same
-    ``frontier_cap`` contract, but no per-level ``(F, scan_budget)``
-    window gather, so the planner's plan pass costs a fraction of a full
+    ``frontier_cap`` contract, but no per-level entry-scan gathers, so
+    the planner's plan pass costs a fraction of a full
     route (DESIGN.md §10)."""
     F = p.frontier_cap
     _require_frontier(F)
     m = di.attrs.shape[1]
     full = (1 << m) - 1
     H = di.nbrs.shape[1]
-
-    fnode0 = jnp.full((F,), -1, jnp.int32).at[0].set(di.root)
-    fD0 = jnp.zeros((F,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m))
 
     def level(_lvl, st):
         fnode, fD, card = st
@@ -305,7 +349,8 @@ def route_level_card(di, qlo: jax.Array, qhi: jax.Array, p) -> jax.Array:
         return fnode, fD, card + jnp.sum(jnp.where(do_scan,
                                                    di.count[node], 0))
 
-    st = jax.lax.fori_loop(0, H, level, (fnode0, fD0, jnp.int32(0)))
+    st = jax.lax.fori_loop(0, H, level, (*_frontier0(di, qlo, qhi, m, F),
+                                         jnp.int32(0)))
     return st[2]
 
 
@@ -330,8 +375,6 @@ def route_level_windows(di, qlo: jax.Array, qhi: jax.Array, p, *,
     full = (1 << m) - 1
     H = di.nbrs.shape[1]
 
-    fnode0 = jnp.full((F,), -1, jnp.int32).at[0].set(di.root)
-    fD0 = jnp.zeros((F,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m))
     wstart0 = jnp.full((W,), _I32_MAX, jnp.int32)   # i32max pads sort last
     wcount0 = jnp.zeros((W,), jnp.int32)
 
@@ -352,8 +395,9 @@ def route_level_windows(di, qlo: jax.Array, qhi: jax.Array, p, *,
                 wstart, wcount, jnp.minimum(fill + jnp.sum(small), W))
 
     st = jax.lax.fori_loop(
-        0, H, level, (fnode0, fD0, jnp.int32(0), jnp.int32(0),
-                      jnp.int32(0), wstart0, wcount0, jnp.int32(0)))
+        0, H, level, (*_frontier0(di, qlo, qhi, m, F), jnp.int32(0),
+                      jnp.int32(0), jnp.int32(0), wstart0, wcount0,
+                      jnp.int32(0)))
     _, _, card, n_small, n_large, wstart, wcount, _ = st
     # antichain extents are disjoint -> starts unique among real windows;
     # stable ascending sort puts the i32max pads last

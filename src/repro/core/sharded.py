@@ -111,14 +111,15 @@ def stack_shards(shards: Sequence[KHIIndex]) -> ShardedKHI:
                             pad_height=max_h)
            for ix in shards]
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *dis)
+    rows = dis[0].n                  # max_n padded to whole device tiles
     waste = (
-        1.0 - sum(ix.n for ix in shards) / (S * max_n),
+        1.0 - sum(ix.n for ix in shards) / (S * rows),
         1.0 - sum(ix.tree.num_nodes for ix in shards) / (S * max_p),
         1.0 - sum(ix.height for ix in shards) / (S * max_h),
     )
     if max(waste) > 0:
         logger.info("stack_shards: pad waste rows=%.4f nodes=%.4f "
-                    "levels=%.4f (S=%d, max_n=%d)", *waste, S, max_n)
+                    "levels=%.4f (S=%d, rows=%d)", *waste, S, rows)
     offsets = jnp.arange(S, dtype=jnp.int32)
     return ShardedKHI(di=stacked, offsets=offsets, pad_waste=waste)
 
@@ -324,8 +325,6 @@ def make_sharded_search_fn(params: SearchParams, mesh: Mesh, *,
     dspec = P(tuple(data_axes))
     EMPTY = (jnp.float32(jnp.inf), jnp.float32(-jnp.inf))
 
-    from jax.experimental.shard_map import shard_map
-
     def merge_k(gids, dists):
         if merge == "halving":
             return _merge_topk_halving(gids, dists, p.k, model_axis,
@@ -437,18 +436,18 @@ def make_sharded_search_fn(params: SearchParams, mesh: Mesh, *,
                       jnp.where(mode2[:, None], m_d, g_d))
         return ids, d
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(model_axis), P(model_axis), dspec, dspec, dspec),
         out_specs=(dspec, dspec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(lambda skhi, q, qlo, qhi: fn(skhi.di, skhi.offsets, q, qlo, qhi))
 
 
 def search_sharded_emulated(skhi: ShardedKHI, queries, qlo, qhi,
                             params: SearchParams, *, dist_fn=None,
-                            on_undersized: str = "adjust"):
+                            on_undersized: str = "adjust", interpret=None):
     """Single-device semantic equivalent of the shard_map program (vmap over
     the shard axis instead of devices) — used by tests on this 1-CPU box.
     Index-dependent buffer bounds are auto-raised by default.
@@ -462,7 +461,7 @@ def search_sharded_emulated(skhi: ShardedKHI, queries, qlo, qhi,
     if params.strategy != "graph":
         from .engine import Planner
         planner = Planner(skhi, params, dist_fn=dist_fn,
-                          on_undersized=on_undersized)
+                          on_undersized=on_undersized, interpret=interpret)
         ids, dists, hops, _ = planner.search(np.asarray(queries),
                                              np.asarray(qlo),
                                              np.asarray(qhi))
@@ -472,7 +471,8 @@ def search_sharded_emulated(skhi: ShardedKHI, queries, qlo, qhi,
     if params.quant != "none" and skhi.di.qvecs is None:
         skhi = dataclasses.replace(
             skhi, di=with_quant_replica(skhi.di, params.quant))
-    scorer, exact = resolve_scorer_pair(params, dist_fn=dist_fn)
+    scorer, exact = resolve_scorer_pair(params, dist_fn=dist_fn,
+                                        interpret=interpret)
     n_shards = skhi.num_shards
 
     @jax.jit

@@ -41,6 +41,12 @@ class DatasetSpec:
     attr_kinds: Optional[tuple[str, ...]] = None  # per-attr: "lognormal"|"year"|"uniform"|"zipf"
     attr_corr: float = 0.5   # 0 = attributes independent of embedding cluster
     seed: int = 0
+    # None = isotropic within-cluster spread; r = spread confined to one
+    # shared random r-dimensional subspace (same total variance). At large
+    # d an isotropic cloud has no neighbour structure (every distance
+    # concentrates near the mean); real embeddings have a low intrinsic
+    # dimension, which is what a graph index navigates.
+    latent_dim: Optional[int] = None
 
 
 # Scaled-down stand-ins for the paper's four datasets (Table 1).
@@ -94,8 +100,14 @@ def make_dataset(spec: DatasetSpec | str):
     centers = rng.standard_normal((spec.n_clusters, spec.d)).astype(np.float32)
     centers /= np.linalg.norm(centers, axis=1, keepdims=True)
     assign = rng.integers(0, spec.n_clusters, size=spec.n)
-    vecs = centers[assign] + spec.cluster_std * rng.standard_normal(
-        (spec.n, spec.d)).astype(np.float32)
+    if spec.latent_dim:
+        r = spec.latent_dim
+        basis = np.linalg.qr(rng.standard_normal((spec.d, r)))[0].T
+        basis = (basis * np.sqrt(spec.d / r)).astype(np.float32)  # (r, d)
+        spread = rng.standard_normal((spec.n, r), dtype=np.float32) @ basis
+    else:
+        spread = rng.standard_normal((spec.n, spec.d)).astype(np.float32)
+    vecs = centers[assign] + spec.cluster_std * spread
     # cluster-tied latent drives the attribute correlation
     cluster_z = rng.standard_normal(spec.n_clusters)
     z = cluster_z[assign]

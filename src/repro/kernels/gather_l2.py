@@ -29,6 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .blocks import (gather_rows, lane_block, row_block, tile_rows,
+                     tile_pad)
+
 __all__ = ["gather_l2_kernel", "gather_l2_raw", "gather_l2_blocked_kernel",
            "gather_l2_blocked_raw"]
 
@@ -64,31 +67,14 @@ def gather_l2_raw(idx: jax.Array, corpus: jax.Array, q: jax.Array,
     )(idx, corpus, q)
 
 
-def gather_l2_blocked_kernel(idx_ref, corpus_ref, q_ref, o_ref, rows_ref,
-                             sems_ref):
-    """Grid (B, C/C_BLK): step (i, j) gathers rows idx[i, j*C_BLK : (j+1)*
-    C_BLK] into the (C_BLK, d) VMEM scratch via C_BLK overlapping DMAs,
-    then reduces the whole tile against query row i in one shot."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    c_blk = rows_ref.shape[0]
-
-    def issue(r, carry):
-        row = idx_ref[i, j * c_blk + r]
-        pltpu.make_async_copy(corpus_ref.at[row], rows_ref.at[r],
-                              sems_ref.at[r]).start()
-        return carry
-
-    jax.lax.fori_loop(0, c_blk, issue, 0)
-
-    def drain(r, carry):
-        row = idx_ref[i, j * c_blk + r]
-        pltpu.make_async_copy(corpus_ref.at[row], rows_ref.at[r],
-                              sems_ref.at[r]).wait()
-        return carry
-
-    jax.lax.fori_loop(0, c_blk, drain, 0)
-    d = q_ref[...].astype(jnp.float32) - rows_ref[...].astype(jnp.float32)
+def gather_l2_blocked_kernel(idx_ref, corpus_ref, q_ref, o_ref, tiles_ref,
+                             rows_ref, sems_ref):
+    """Grid (B, C/C_BLK): step (i, j) gathers rows idx[i, j*C_BLK :
+    (j+1)*C_BLK] into a (C_BLK, d) VMEM tile via C_BLK overlapping tile
+    DMAs (``blocks.gather_rows``), then reduces the whole tile against
+    query row i in one shot."""
+    gather_rows(idx_ref, corpus_ref, tiles_ref, rows_ref, sems_ref)
+    d = q_ref[...].astype(jnp.float32) - rows_ref[...]
     o_ref[...] = jnp.sum(d * d, axis=-1)[None, :]
 
 
@@ -102,7 +88,8 @@ def gather_l2_blocked_raw(idx: jax.Array, corpus: jax.Array, q: jax.Array,
     ``c_blk`` with index 0 (any in-range row — the padded lanes' distances
     are sliced off before returning, mirroring the engine's convention that
     invalid slots get their distances overwritten upstream); the corpus is
-    never reshaped or copied, only DMA'd row-wise into the scratch tile."""
+    DMA'd tile by tile (padded by ``kernels.blocks.tile_pad``, a copy only
+    when its row count is not a tile multiple)."""
     B, C = idx.shape
     N, D = corpus.shape
     c_blk = min(c_blk, C)
@@ -117,17 +104,19 @@ def gather_l2_blocked_raw(idx: jax.Array, corpus: jax.Array, q: jax.Array,
             grid=(B, n_blk),
             in_specs=[
                 # corpus stays whole in compiler-chosen (HBM) memory; the
-                # kernel DMAs the selected rows itself
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((1, D), lambda i, j, idx_ref: (i, 0)),
+                # kernel DMAs the tiles holding the selected rows itself
+                pl.BlockSpec(memory_space=pl.ANY),
+                row_block(D),
             ],
-            out_specs=pl.BlockSpec((1, c_blk), lambda i, j, idx_ref: (i, j)),
+            out_specs=lane_block(c_blk, lambda j: j),
             scratch_shapes=[
-                pltpu.VMEM((c_blk, D), corpus.dtype),
+                pltpu.VMEM((c_blk, tile_rows(corpus.dtype), D),
+                           corpus.dtype),
+                pltpu.VMEM((c_blk, D), jnp.float32),    # the picked rows
                 pltpu.SemaphoreType.DMA((c_blk,)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, n_blk * c_blk), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, n_blk * c_blk), jnp.float32),
         interpret=interpret,
-    )(idx, corpus, q)
-    return out[:, :C]
+    )(idx, tile_pad(corpus), q[:, None])
+    return out[:, 0, :C]

@@ -151,8 +151,9 @@ def gather_l2_filtered_q8(idx: jax.Array, qcorpus: jax.Array,
                           c_blk: int = 128) -> jax.Array:
     """int8-replica form of ``gather_l2_filtered`` (DESIGN.md §12):
     idx (B, C) into qcorpus (N, d) int8 + qscale (N, 1) f32, dequantized
-    in-kernel — d + 4 HBM bytes per candidate row instead of 4d. Oracle:
-    ``gather_l2_filter_q8_ref``."""
+    in-kernel. On TPU each candidate DMAs its whole 32-row int8 tile
+    (32·d bytes, as many as an f32 candidate's 8-row tile), so the gather
+    saves VMEM work, not HBM bytes. Oracle: ``gather_l2_filter_q8_ref``."""
     return _gather_l2_filtered_q8(idx, qcorpus, qscale, attrs, q, qlo, qhi,
                                   _auto_interpret(interpret), c_blk)
 

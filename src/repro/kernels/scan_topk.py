@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .blocks import lane_block, row_block
+
 __all__ = ["scan_topk_kernel", "scan_topk_raw",
            "scan_topk_q8_kernel", "scan_topk_q8_raw",
            "scan_topk_mask_kernel", "scan_topk_mask_raw",
@@ -64,7 +66,6 @@ def scan_topk_kernel(corpus_ref, attrs_ref, q_ref, qlo_ref, qhi_ref,
     running (1, k) top-k carried in the revisited output blocks."""
     j = pl.program_id(1)
     n_blk = corpus_ref.shape[0]
-    k = ids_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -76,24 +77,8 @@ def scan_topk_kernel(corpus_ref, attrs_ref, q_ref, qlo_ref, qhi_ref,
     a = attrs_ref[...].astype(jnp.float32)               # (n_blk, m)
     ok = jnp.all((a >= qlo_ref[...]) & (a <= qhi_ref[...]), axis=-1)
     rows = j * n_blk + jax.lax.broadcasted_iota(jnp.int32, (1, n_blk), 1)
-
-    cand_d = jnp.concatenate(
-        [dists_ref[...], jnp.where(ok, dist, jnp.inf)[None, :]], axis=1)
-    cand_i = jnp.concatenate([ids_ref[...], rows], axis=1)
-
-    def take(t, carry):
-        cd, ci, od, oi = carry
-        pos = jnp.argmin(cd, axis=1)[0]      # first min: lowest-id tie-break
-        dmin = cd[0, pos]
-        od = od.at[0, t].set(dmin)
-        oi = oi.at[0, t].set(jnp.where(jnp.isinf(dmin), -1, ci[0, pos]))
-        cd = cd.at[0, pos].set(jnp.inf)
-        return cd, ci, od, oi
-
-    _, _, od, oi = jax.lax.fori_loop(
-        0, k, take, (cand_d, cand_i, dists_ref[...], ids_ref[...]))
-    dists_ref[...] = od
-    ids_ref[...] = oi
+    _fold_tile_topk(jnp.where(ok, dist, jnp.inf)[None, :], rows, ids_ref,
+                    dists_ref)
 
 
 def scan_topk_raw(corpus: jax.Array, attrs: jax.Array, q: jax.Array,
@@ -126,45 +111,53 @@ def scan_topk_raw(corpus: jax.Array, attrs: jax.Array, q: jax.Array,
         in_specs=[
             pl.BlockSpec((n_blk, D), lambda i, j: (j, 0)),   # corpus tile
             pl.BlockSpec((n_blk, M), lambda i, j: (j, 0)),   # attrs tile
-            pl.BlockSpec((1, D), lambda i, j: (i, 0)),       # query row
-            pl.BlockSpec((1, M), lambda i, j: (i, 0)),       # qlo row
-            pl.BlockSpec((1, M), lambda i, j: (i, 0)),       # qhi row
+            row_block(D),                                    # query row
+            row_block(M),                                    # qlo row
+            row_block(M),                                    # qhi row
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running ids
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running dists
+            lane_block(k, lambda j: 0),                      # running ids
+            lane_block(k, lambda j: 0),                      # running dists
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
         interpret=interpret,
-    )(corpus, attrs, q, qlo, qhi)
-    return ids, dists
+    )(corpus, attrs, q[:, None], qlo[:, None], qhi[:, None])
+    return ids[:, 0], dists[:, 0]
 
 
-def _fold_tile_topk(dist, ok, rows, ids_ref, dists_ref):
-    """Fold one scored tile into the running (1, k) top-k carried in the
-    revisited output blocks (the streaming step shared by the quantized
-    and windowed scan kernels; same extraction order as
-    ``scan_topk_kernel`` — (distance, stream position), so with tiles
-    arriving in ascending row order ties break to the lowest id)."""
+def _fold_tile_topk(tile_d, rows, ids_ref, dists_ref):
+    """Fold one scored tile — (1, n) distances, +inf where the row fails
+    the predicate, and its (1, n) row ids — into the running (1, k) top-k
+    carried in the revisited output blocks (the step every scan kernel
+    shares).
+    Extraction order is (distance, stream position), so with tiles
+    arriving in ascending row order ties break to the lowest id. Each of
+    the k steps is a masked extraction — the minimum, the lowest lane that
+    holds it, select-based writes — because Mosaic has no dynamic lane
+    indexing (``argmin`` + ``x[0, pos]`` + ``.at[0, t].set``)."""
     k = ids_ref.shape[1]
-    cand_d = jnp.concatenate(
-        [dists_ref[...], jnp.where(ok, dist, jnp.inf)[None, :]], axis=1)
+    cand_d = jnp.concatenate([dists_ref[...], tile_d], axis=1)
     cand_i = jnp.concatenate([ids_ref[...], rows], axis=1)
+    L = cand_d.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
     def take(t, carry):
-        cd, ci, od, oi = carry
-        pos = jnp.argmin(cd, axis=1)[0]      # first min: lowest-id tie-break
-        dmin = cd[0, pos]
-        od = od.at[0, t].set(dmin)
-        oi = oi.at[0, t].set(jnp.where(jnp.isinf(dmin), -1, ci[0, pos]))
-        cd = cd.at[0, pos].set(jnp.inf)
-        return cd, ci, od, oi
+        cd, od, oi = carry
+        dmin = jnp.min(cd, axis=1, keepdims=True)                  # (1, 1)
+        pos = jnp.min(jnp.where(cd == dmin, lane, L), axis=1,
+                      keepdims=True)         # first min: lowest-id tie-break
+        hit = lane == pos
+        idv = jnp.max(jnp.where(hit, cand_i, -1), axis=1, keepdims=True)
+        od = jnp.where(slot == t, dmin, od)
+        oi = jnp.where(slot == t, jnp.where(jnp.isinf(dmin), -1, idv), oi)
+        return jnp.where(hit, jnp.inf, cd), od, oi
 
-    _, _, od, oi = jax.lax.fori_loop(
-        0, k, take, (cand_d, cand_i, dists_ref[...], ids_ref[...]))
+    _, od, oi = jax.lax.fori_loop(
+        0, k, take, (cand_d, dists_ref[...], ids_ref[...]))
     dists_ref[...] = od
     ids_ref[...] = oi
 
@@ -189,7 +182,8 @@ def scan_topk_q8_kernel(corpus_ref, scale_ref, attrs_ref, q_ref, qlo_ref,
     a = attrs_ref[...].astype(jnp.float32)               # (n_blk, m)
     ok = jnp.all((a >= qlo_ref[...]) & (a <= qhi_ref[...]), axis=-1)
     rows = j * n_blk + jax.lax.broadcasted_iota(jnp.int32, (1, n_blk), 1)
-    _fold_tile_topk(dist, ok, rows, ids_ref, dists_ref)
+    _fold_tile_topk(jnp.where(ok, dist, jnp.inf)[None, :], rows, ids_ref,
+                    dists_ref)
 
 
 def scan_topk_q8_raw(qcorpus: jax.Array, qscale: jax.Array,
@@ -221,21 +215,21 @@ def scan_topk_q8_raw(qcorpus: jax.Array, qscale: jax.Array,
             pl.BlockSpec((n_blk, D), lambda i, j: (j, 0)),   # int8 tile
             pl.BlockSpec((n_blk, 1), lambda i, j: (j, 0)),   # scale plane
             pl.BlockSpec((n_blk, M), lambda i, j: (j, 0)),   # attrs tile
-            pl.BlockSpec((1, D), lambda i, j: (i, 0)),       # query row
-            pl.BlockSpec((1, M), lambda i, j: (i, 0)),       # qlo row
-            pl.BlockSpec((1, M), lambda i, j: (i, 0)),       # qhi row
+            row_block(D),                                    # query row
+            row_block(M),                                    # qlo row
+            row_block(M),                                    # qhi row
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running ids
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running dists
+            lane_block(k, lambda j: 0),                      # running ids
+            lane_block(k, lambda j: 0),                      # running dists
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
         interpret=interpret,
-    )(qcorpus, qscale, attrs, q, qlo, qhi)
-    return ids, dists
+    )(qcorpus, qscale, attrs, q[:, None], qlo[:, None], qhi[:, None])
+    return ids[:, 0], dists[:, 0]
 
 
 def scan_topk_mask_kernel(corpus_ref, mask_ref, q_ref, ids_ref, dists_ref):
@@ -257,7 +251,8 @@ def scan_topk_mask_kernel(corpus_ref, mask_ref, q_ref, ids_ref, dists_ref):
     dist = jnp.sum(d * d, axis=-1)                       # (n_blk,)
     ok = mask_ref[...][:, 0] > 0.0                       # (n_blk,)
     rows = j * n_blk + jax.lax.broadcasted_iota(jnp.int32, (1, n_blk), 1)
-    _fold_tile_topk(dist, ok, rows, ids_ref, dists_ref)
+    _fold_tile_topk(jnp.where(ok, dist, jnp.inf)[None, :], rows, ids_ref,
+                    dists_ref)
 
 
 def scan_topk_mask_raw(corpus: jax.Array, mask: jax.Array, q: jax.Array,
@@ -287,19 +282,19 @@ def scan_topk_mask_raw(corpus: jax.Array, mask: jax.Array, q: jax.Array,
         in_specs=[
             pl.BlockSpec((n_blk, D), lambda i, j: (j, 0)),   # corpus tile
             pl.BlockSpec((n_blk, 1), lambda i, j: (j, 0)),   # mask plane
-            pl.BlockSpec((1, D), lambda i, j: (i, 0)),       # query row
+            row_block(D),                                    # query row
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running ids
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),       # running dists
+            lane_block(k, lambda j: 0),                      # running ids
+            lane_block(k, lambda j: 0),                      # running dists
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
         interpret=interpret,
-    )(corpus, mask, q)
-    return ids, dists
+    )(corpus, mask, q[:, None])
+    return ids[:, 0], dists[:, 0]
 
 
 def scan_topk_windows_kernel(starts_ref, counts_ref, corpus_ref, attrs_ref,
@@ -310,15 +305,15 @@ def scan_topk_windows_kernel(starts_ref, counts_ref, corpus_ref, attrs_ref,
     position-ordered corpus and folds it into query i's running (1, k)
     top-k (DESIGN.md §12 — the hybrid planner's per-node scan).
 
-    The window slice DMAs as ONE contiguous (w_cap, d) block (plus its
-    attrs block) — the sequential-stream shape HBM likes — with lanes
-    beyond ``counts[i, w]`` masked out; pad windows (start = -1) carry
-    count 0, so every lane masks and the DMA (clamped to row 0) is
+    The window slice DMAs as ONE contiguous block of whole tiles covering
+    it (plus its attrs block) — the sequential-stream shape HBM likes —
+    with lanes outside the window masked out; pad windows (start = -1)
+    carry count 0, so every lane masks and the DMA (clamped to row 0) is
     harmless. Emitted ids are POSITIONS; the caller maps them back
     through the DFS ``order`` permutation."""
     i = pl.program_id(0)
     w = pl.program_id(1)
-    w_cap = rows_ref.shape[0]
+    w_tot = rows_ref.shape[0]
 
     @pl.when(w == 0)
     def _init():
@@ -327,9 +322,13 @@ def scan_topk_windows_kernel(starts_ref, counts_ref, corpus_ref, attrs_ref,
 
     s = jnp.maximum(starts_ref[i, w], 0)
     cnt = counts_ref[i, w]
-    vdma = pltpu.make_async_copy(corpus_ref.at[pl.dslice(s, w_cap)],
+    # DMA whole tiles from a 128-aligned base (the attrs plane rides
+    # transposed, lane-dense, so its window is a lane slice); the buffers
+    # are sized for the worst offset and lanes outside [s, s + cnt) mask
+    base = pl.multiple_of(s // 128 * 128, 128)
+    vdma = pltpu.make_async_copy(corpus_ref.at[pl.ds(base, w_tot)],
                                  rows_ref, vsem_ref)
-    adma = pltpu.make_async_copy(attrs_ref.at[pl.dslice(s, w_cap)],
+    adma = pltpu.make_async_copy(attrs_ref.at[:, pl.ds(base, w_tot)],
                                  arows_ref, asem_ref)
     vdma.start()
     adma.start()
@@ -337,13 +336,19 @@ def scan_topk_windows_kernel(starts_ref, counts_ref, corpus_ref, attrs_ref,
     adma.wait()
 
     d = q_ref[...].astype(jnp.float32) - rows_ref[...].astype(jnp.float32)
-    dist = jnp.sum(d * d, axis=-1)                       # (w_cap,)
-    a = arows_ref[...].astype(jnp.float32)               # (w_cap, m)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (w_cap,), 0)
-    ok = (jnp.all((a >= qlo_ref[...]) & (a <= qhi_ref[...]), axis=-1)
-          & (lane < cnt))
-    pos = (s + jax.lax.broadcasted_iota(jnp.int32, (1, w_cap), 1))
-    _fold_tile_topk(dist, ok, pos, ids_ref, dists_ref)
+    dist = jnp.sum(d * d, axis=-1)                       # (w_tot,)
+    a = arows_ref[...].astype(jnp.float32)               # (m, w_tot)
+    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, w_tot), 1)
+    ok = (jnp.all((a >= qlo_ref[...]) & (a <= qhi_ref[...]), axis=0,
+                  keepdims=True)
+          & (pos >= s) & (pos < s + cnt))
+    _fold_tile_topk(jnp.where(ok, dist[None, :], jnp.inf), pos, ids_ref,
+                    dists_ref)
+
+
+def _col(m: int) -> pl.BlockSpec:
+    """Query i's (m, 1) column of a (B, m, 1) operand."""
+    return pl.BlockSpec((None, m, 1), lambda i, *_: (i, 0, 0))
 
 
 def scan_topk_windows_raw(corpus: jax.Array, attrs: jax.Array,
@@ -361,9 +366,9 @@ def scan_topk_windows_raw(corpus: jax.Array, attrs: jax.Array,
     Bit-parity tie-break contract: windows must arrive sorted ascending
     by start per lane (the planner sorts), so stream position order ==
     global position order and ties break to the lowest position exactly
-    like ``lax.top_k``. The corpus pads with ``w_cap`` NaN-attr rows so
-    a window starting near N can DMA its full (w_cap, d) slice without
-    running off the buffer."""
+    like ``lax.top_k``. The corpus pads with NaN-attr rows so a window
+    starting near N can DMA all its tiles without running off the
+    buffer."""
     B = q.shape[0]
     N, D = corpus.shape
     M = attrs.shape[1]
@@ -372,35 +377,36 @@ def scan_topk_windows_raw(corpus: jax.Array, attrs: jax.Array,
         raise ValueError(f"w_cap must be >= 1, got {w_cap}")
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
-    corpus = jnp.pad(corpus, ((0, w_cap), (0, 0)))
-    attrs = jnp.pad(attrs, ((0, w_cap), (0, 0)), constant_values=jnp.nan)
+    w_tot = -(-(w_cap + 127) // 128) * 128   # aligned span of any window
+    corpus = jnp.pad(corpus, ((0, w_tot), (0, 0)))
+    attrs = jnp.pad(attrs, ((0, w_tot), (0, 0)), constant_values=jnp.nan)
     ids, dists = pl.pallas_call(
         scan_topk_windows_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, W),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),    # corpus (windows DMA)
-                pl.BlockSpec(memory_space=pltpu.ANY),    # attrs  (windows DMA)
-                pl.BlockSpec((1, D), lambda i, w, s_ref, c_ref: (i, 0)),
-                pl.BlockSpec((1, M), lambda i, w, s_ref, c_ref: (i, 0)),
-                pl.BlockSpec((1, M), lambda i, w, s_ref, c_ref: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),    # corpus (windows DMA)
+                pl.BlockSpec(memory_space=pl.ANY),    # attrs  (windows DMA)
+                row_block(D),
+                _col(M), _col(M),                       # qlo / qhi columns
             ],
             out_specs=[
-                pl.BlockSpec((1, k), lambda i, w, s_ref, c_ref: (i, 0)),
-                pl.BlockSpec((1, k), lambda i, w, s_ref, c_ref: (i, 0)),
+                lane_block(k, lambda w: 0),             # running ids
+                lane_block(k, lambda w: 0),             # running dists
             ],
             scratch_shapes=[
-                pltpu.VMEM((w_cap, D), corpus.dtype),
-                pltpu.VMEM((w_cap, M), attrs.dtype),
+                pltpu.VMEM((w_tot, D), corpus.dtype),
+                pltpu.VMEM((M, w_tot), attrs.dtype),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
         interpret=interpret,
-    )(starts, counts, corpus, attrs, q, qlo, qhi)
-    return ids, dists
+    )(starts, counts, corpus, attrs.T, q[:, None], qlo[:, :, None],
+      qhi[:, :, None])
+    return ids[:, 0], dists[:, 0]

@@ -4,8 +4,9 @@
 (micro-batching + shard fan-out + result cache, DESIGN.md §3) and drives it
 with a stream of mixed-size request bursts — the serving workload, not just
 a fixed-batch loop. ``--shards S`` serves a sharded corpus, ``--backend``
-picks the scoring backend (``pallas_gather_l2_filter`` = the
-predicate-fused kernel), ``--router`` the Phase-A tree router,
+picks the scoring backend (default ``pallas_gather_l2_filter``, the
+predicate-fused kernel of ``configs/khi_serve.py``; ``jnp`` runs no
+kernel), ``--router`` the Phase-A tree router,
 ``--strategy`` the execution strategy (``auto`` = per-query planner
 dispatch between graph search and the exact brute scan, DESIGN.md §10;
 ``--scan-threshold`` overrides the derived dispatch threshold);
@@ -287,7 +288,12 @@ def main(argv=None):
                          "(DESIGN.md §14) — needs at least --shards "
                          "devices; emulate on CPU with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N")
-    ap.add_argument("--backend", default="jnp", choices=list(BACKENDS))
+    from repro.configs.khi_serve import config as khi_serve
+
+    # the production scorer (configs/khi_serve.py): a Pallas kernel, run
+    # by Mosaic on a TPU and by the interpreter elsewhere
+    ap.add_argument("--backend", default=khi_serve().backend,
+                    choices=list(BACKENDS))
     ap.add_argument("--expand-width", type=int, default=1,
                     help="frontier width E: pool entries expanded per hop")
     ap.add_argument("--router", default="level", choices=list(ROUTERS),
@@ -351,6 +357,9 @@ def main(argv=None):
                          "force a compaction")
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     if args.mode == "khi":
         serve_khi(args)
     else:

@@ -177,7 +177,8 @@ class KHIService:
     def __init__(self, index, params: Optional[SearchParams] = None, *,
                  config: Optional[ServeConfig] = None, mesh=None,
                  dist_fn=None, on_undersized: str = "adjust",
-                 tiers: Sequence[SearchParams] = ()):
+                 tiers: Sequence[SearchParams] = (),
+                 interpret: Optional[bool] = None):
         if on_undersized not in ("raise", "adjust", "ignore"):
             # fail at construction, not on the first undersized search
             raise ValueError(f"on_undersized must be raise|adjust|ignore, "
@@ -188,6 +189,8 @@ class KHIService:
         self._on_undersized = on_undersized
         self.config = config or ServeConfig()
         self._legacy_dist_fn = dist_fn
+        # Pallas interpret mode: None = interpreter off the TPU only
+        self._interpret = interpret
         self._mesh = mesh
         self.epoch = 0
         self._cache: "collections.OrderedDict[bytes, Tuple[np.ndarray, np.ndarray]]" = (
@@ -285,6 +288,7 @@ class KHIService:
         self._planners: dict = {}
         self._pred_planners: dict = {}   # bitmask-fallback tiers (§15)
         self._search_fns: dict = {}
+        self._hlo_fns: dict = {}         # tier -> batch -> {program: HLO}
         self._search = self._get_search_fn(0)   # prebuild the hot tier
 
     def swap_index(self, index, *, params: Optional[SearchParams] = None,
@@ -352,7 +356,8 @@ class KHIService:
         # a rebuild. The old-epoch drain in swap_index still runs against
         # the old index — the flush happens before _install_index rebinds.
         p = self._tier_params[tier]
-        scorer, exact = resolve_scorer_pair(p, dist_fn=self._legacy_dist_fn)
+        scorer, exact = resolve_scorer_pair(p, dist_fn=self._legacy_dist_fn,
+                                            interpret=self._interpret)
         if self._mesh is not None:
             # collective pipeline (DESIGN.md §14): every strategy and
             # quant tier lowers through one shard_map program — planner
@@ -362,7 +367,9 @@ class KHIService:
             fn = make_sharded_search_fn(p, self._mesh,
                                         dist_fn=self._legacy_dist_fn,
                                         skhi=self.index,
-                                        on_undersized=self._on_undersized)
+                                        on_undersized=self._on_undersized,
+                                        interpret=self._interpret)
+            self._hlo_fns[tier] = self._jit_hlo("collective", fn)
             return lambda q, lo, hi: fn(self.index, q, lo, hi)
         if p.strategy != "graph":
             # planner-backed path (DESIGN.md §10): per-lane dispatch to the
@@ -371,9 +378,11 @@ class KHIService:
             # Every tier's planner shares ONE plan cache (§13): the cached
             # routing bound is box-keyed and tier-invariant.
             planner = Planner(self.index, p, dist_fn=self._legacy_dist_fn,
+                              interpret=self._interpret,
                               on_undersized=self._on_undersized,
                               plan_cache=self._plan_cache,
                               plan_salt=self.epoch.to_bytes(8, "little"))
+            self._hlo_fns[tier] = planner.compiled_text
             if self._stream is not None:
                 # a tier first used after streaming deletes must see the
                 # tombstone-adjusted cardinalities (DESIGN.md §11)
@@ -402,6 +411,7 @@ class KHIService:
                     lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
                 return ids, dists
 
+            self._hlo_fns[tier] = self._jit_hlo("graph", single)
             return lambda q, lo, hi: single(self.index, q, lo, hi)
 
         n_shards = self.index.num_shards
@@ -414,7 +424,26 @@ class KHIService:
             gids, dists, _ = jax.vmap(per_shard)(skhi.di, skhi.offsets)
             return _merge_topk(gids, dists, p.k)
 
+        self._hlo_fns[tier] = self._jit_hlo("graph", fanout)
         return lambda q, lo, hi: fanout(self.index, q, lo, hi)
+
+    def _jit_hlo(self, name: str, fn):
+        """batch -> {name: compiled HLO text} for a program called as
+        ``fn(self.index, q, lo, hi)``."""
+        def hlo(batch: int) -> dict:
+            q = jax.ShapeDtypeStruct((batch, self.d), jnp.float32)
+            box = jax.ShapeDtypeStruct((batch, self.m), jnp.float32)
+            return {name: fn.lower(self.index, q, box, box).compile()
+                    .as_text()}
+        return hlo
+
+    def compiled_hlo(self, batch: int, tier: int = 0) -> dict:
+        """Compiled HLO text of every whole-batch device program serving
+        ``tier`` at a ``batch``-lane bucket, by program name — what a
+        caller checks to see that the Pallas kernels lowered to Mosaic
+        (``tpu_custom_call``) rather than running interpreted."""
+        self._get_search_fn(tier)
+        return self._hlo_fns[tier](batch)
 
     def _bucket(self, b: int) -> int:
         for size in self.config.buckets:

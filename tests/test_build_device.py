@@ -45,12 +45,16 @@ def test_row_blocked_large_node_path_matches():
     np.testing.assert_array_equal(dev, ref)
 
 
-def test_pallas_l2dist_path_matches():
-    """The Pallas l2dist candidate path (interpreter on CPU) reproduces the
-    numpy builder too — the kernel is a perf transform, not a semantic one."""
-    vecs, attrs, tree = _random_case(300, 24, 3, 0)
+def test_distance_block_cap_shrinks_row_block(monkeypatch):
+    """A large node whose (row block, C) distance block would pass the cap
+    runs in smaller row blocks, with not a single row changed."""
+    from repro.core import build_device
+
+    vecs, attrs, tree = _random_case(700, 24, 3, 0)
     ref = hnsw.build_graphs_bulk(tree, vecs, M=8)
-    dev = build_graphs_device(tree, vecs, M=8, dist="pallas")
+    # C = 1024 at the root: a 64 KiB cap leaves 16-row blocks
+    monkeypatch.setattr(build_device, "_BLOCK_BYTES", 1 << 16)
+    dev = build_graphs_device(tree, vecs, M=8, large_node=256, row_block=128)
     np.testing.assert_array_equal(dev, ref)
 
 

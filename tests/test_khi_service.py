@@ -280,6 +280,20 @@ def test_cache_keys_invalidate_across_back_to_back_swaps(tiny_data,
         assert svc.epoch == epoch
 
 
+@pytest.mark.parametrize("strategy,programs", [
+    ("graph", {"graph"}), ("auto", {"graph", "scan"})])
+def test_compiled_hlo_names_each_program(tiny_index, strategy, programs):
+    """compiled_hlo returns the compiled text of every whole-batch program
+    that serves a bucket: one graph program, or the planner's graph and
+    scan programs under strategy="auto"."""
+    svc = KHIService(tiny_index, SearchParams(k=10, ef=32, c_n=16,
+                                              strategy=strategy),
+                     config=ServeConfig(buckets=(8,), cache_size=0))
+    hlo = svc.compiled_hlo(8)
+    assert set(hlo) == programs
+    assert all(text.startswith("HloModule") for text in hlo.values())
+
+
 def test_bad_bucket_config_rejected():
     with pytest.raises(ValueError, match="buckets"):
         ServeConfig(buckets=(32, 8))

@@ -306,15 +306,14 @@ def test_halving_merge_collective_unit():
     gids = rng.integers(0, 999, (S, B, k)).astype(np.int32)
     ref_i, ref_d = _merge_topk(jnp.asarray(gids), jnp.asarray(dists), k)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(g, d):
         return _merge_topk_halving(g[0], d[0], k, "model", S)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("model"), P("model")),
-                   out_specs=(P(None), P(None)), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("model"), P("model")),
+                       out_specs=(P(None), P(None)), check_vma=False)
     ci, cd = jax.jit(fn)(jnp.asarray(gids), jnp.asarray(dists))
     np.testing.assert_array_equal(np.asarray(ci), np.asarray(ref_i))
     np.testing.assert_array_equal(np.asarray(cd), np.asarray(ref_d))

@@ -94,6 +94,31 @@ def test_with_quant_replica_roundtrip():
         eng.with_quant_replica(di, "fp4")
 
 
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_device_rows_pad_to_whole_tiles(quant):
+    """Device rows pad to whole int8 HBM tiles (32 rows), so the gather
+    kernels never copy the corpus; the pad rows are never answered, even
+    for an unbounded box (their +inf attrs would pass it)."""
+    from repro.core.query_ref import Predicate
+
+    rng = np.random.default_rng(3)
+    vecs = rng.standard_normal((70, 8)).astype(np.float32)
+    idx = KHIIndex.build(vecs, rng.uniform(0, 1, (70, 2)).astype(np.float32),
+                         KHIConfig(M=8))
+    di = eng.device_put_index(idx, quant=quant)
+    assert di.vecs.shape[0] == 96
+    if quant == "int8":
+        assert di.qvecs.shape[0] == di.qscale.shape[0] == 96
+    assert np.all(np.asarray(di.nbrs)[70:] == -1)
+    everything = [Predicate([-np.inf] * 2, [np.inf] * 2)] * 2
+    p = eng.SearchParams(k=80, ef=96, strategy="scan", quant=quant,
+                         backend="pallas_gather_l2_filter")
+    ids, _, _ = eng.search_batch(di, vecs[:2], everything, p)
+    np.testing.assert_array_equal(np.sort(ids[:, :70], axis=1),
+                                  np.tile(np.arange(70), (2, 1)))
+    assert np.all(ids[:, 70:] == -1)
+
+
 # ----------------------------------------------- kernel vs oracle parity
 
 @pytest.mark.parametrize("B,C,N,D,M", [(2, 8, 40, 8, 2), (3, 33, 200, 24, 3)])

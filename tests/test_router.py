@@ -50,6 +50,27 @@ def test_routers_agree_tier1(tiny_index, tiny_queries):
     _assert_all_equal(_route_all(tiny_index, preds), "tier1")
 
 
+@pytest.mark.parametrize("step", [1, 3, 8])
+def test_level_router_entry_scan_steps(tiny_index, tiny_queries,
+                                       monkeypatch, step):
+    """The level router reads each scanned node ``_SCAN_STEP`` objects at
+    a time; a step far below the nodes' sizes (several steps per node)
+    still finds the same entries and cardinality as the DFS router's
+    whole-window scan."""
+    _, preds = tiny_queries
+    di = eng.device_put_index(tiny_index)
+    p = eng.derive_search_params(
+        eng.SearchParams(k=10, ef=32, c_e=10, c_n=16), di)
+    assert p.scan_budget > step
+    monkeypatch.setattr(rt, "_SCAN_STEP", step)
+    for pr in preds[:8]:
+        qlo, qhi = jnp.asarray(pr.lo), jnp.asarray(pr.hi)
+        e_lvl, card = rt.route_level_sync(di, qlo, qhi, p)
+        e_dfs, _ = rt.route_dfs(di, qlo, qhi, p)
+        np.testing.assert_array_equal(np.asarray(e_lvl), np.asarray(e_dfs))
+        assert int(card) == int(rt.route_level_card(di, qlo, qhi, p))
+
+
 def test_level_router_is_engine_default(tiny_index, tiny_queries):
     """The engine's default params route through the level-sync sweep and
     still match the DFS engine bit-for-bit."""
