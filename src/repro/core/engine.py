@@ -75,6 +75,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 import collections
 import hashlib
@@ -746,8 +747,11 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     L = E * HM                               # fused candidate stream length
 
     # Phase A: tree routing (level-sync sweep or legacy DFS — core.router);
-    # the card byproduct is the planner's signal (§10) — unused in-graph
-    entries, _ = resolve_router(p.router)(di, qlo, qhi, p)
+    # the card byproduct is the planner's signal (§10) — unused in-graph.
+    # The two named scopes are op_name metadata only: they name the
+    # phases in a profile of every graph program (DESIGN.md §3)
+    with jax.named_scope("khi_router"):
+        entries, _ = resolve_router(p.router)(di, qlo, qhi, p)
     e_valid = entries >= 0
     e_dist = scorer.score(di, q, qlo, qhi, entries)
 
@@ -817,8 +821,9 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
         pool = beam.pool_merge_tail(pool, p.ef, buf, bd, bvalid)
         return pool, visited, seen, hops + 1
 
-    pool, visited, seen, hops = jax.lax.while_loop(
-        cond, body, (pool0, visited, seen0, jnp.int32(0)))
+    with jax.named_scope("khi_hop_loop"):
+        pool, visited, seen, hops = jax.lax.while_loop(
+            cond, body, (pool0, visited, seen0, jnp.int32(0)))
     if exact_scorer is None:
         return pool.ids[: p.k], pool.dists[: p.k], hops
     # quantized rerank tail (DESIGN.md §12): the loop above ranked the
@@ -1062,6 +1067,13 @@ def _merge_dedup_jnp(ids_a, d_a, ids_b, d_b, k: int):
     return jnp.where(jnp.isinf(out_d), -1, out_i).astype(jnp.int32), out_d
 
 
+def _fetch(program: str, out) -> tuple:
+    """Wait for a device program's outputs and copy them to the host,
+    under the ``khi.fetch`` profiler span."""
+    with TraceAnnotation("khi.fetch", program=program):
+        return tuple(np.asarray(x) for x in out)
+
+
 @dataclasses.dataclass
 class Plan:
     """Host-side record of one batch's dispatch decisions.
@@ -1079,7 +1091,12 @@ class Plan:
     (graph walk + windows over the small nodes, streams merged);
     ``small_nodes`` holds one (B, P) bool mask per shard (antichain ∩
     count <= node_threshold — the windows' node set) and ``n_windows``
-    the per-lane total across shards."""
+    the per-lane total across shards.
+
+    ``graph_runs`` is filled by ``Planner.search``: one ``(lanes, hops)``
+    pair per graph program run that served the batch — the lanes it ran,
+    pow2 pads included, and its largest hop count. Their product is the
+    lane-iterations the lockstep hop loop paid for."""
 
     card: np.ndarray       # (B,) int64/int32
     use_scan: np.ndarray   # (B,) bool
@@ -1088,6 +1105,7 @@ class Plan:
     mode: Optional[np.ndarray] = None         # (B,) int8, hybrid only
     n_windows: Optional[np.ndarray] = None    # (B,) int64, hybrid only
     small_nodes: Optional[list] = None        # per-shard (B, P) bool
+    graph_runs: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -1481,25 +1499,32 @@ class Planner:
         lanes report hops = 0 (no graph walk)."""
         p = self.params
         use_kernel = p.backend == "pallas_gather_l2_filter"
-        q, qlo_, qhi_ = (jnp.asarray(qs), jnp.asarray(lo), jnp.asarray(hi))
-        if not self._sharded:
-            ids, dd = _windows_one(
-                self._pos_vecs, self._pos_attrs, self.index.order,
-                q, qlo_, qhi_, jnp.asarray(starts[0]),
-                jnp.asarray(counts[0]), k=p.k, w_cap=w_cap,
-                use_kernel=use_kernel, interpret=self._interpret)
-        else:
-            ids, dd = _windows_sharded(
-                self._pos_vecs, self._pos_attrs, self.index.di.order,
-                self.index.offsets, q, qlo_, qhi_, jnp.asarray(starts),
-                jnp.asarray(counts), k=p.k, w_cap=w_cap,
-                use_kernel=use_kernel, interpret=self._interpret)
-        return (np.asarray(ids), np.asarray(dd),
-                np.zeros(qs.shape[0], np.int32))
+        with TraceAnnotation("khi.dispatch", program="windows"):
+            q, qlo_, qhi_ = (jnp.asarray(qs), jnp.asarray(lo),
+                             jnp.asarray(hi))
+            if not self._sharded:
+                out = _windows_one(
+                    self._pos_vecs, self._pos_attrs, self.index.order,
+                    q, qlo_, qhi_, jnp.asarray(starts[0]),
+                    jnp.asarray(counts[0]), k=p.k, w_cap=w_cap,
+                    use_kernel=use_kernel, interpret=self._interpret)
+            else:
+                out = _windows_sharded(
+                    self._pos_vecs, self._pos_attrs, self.index.di.order,
+                    self.index.offsets, q, qlo_, qhi_, jnp.asarray(starts),
+                    jnp.asarray(counts), k=p.k, w_cap=w_cap,
+                    use_kernel=use_kernel, interpret=self._interpret)
+        ids, dd = _fetch("windows", out)
+        return ids, dd, np.zeros(qs.shape[0], np.int32)
 
     # -------------------------------------------------------- host dispatch
     def plan(self, qlo: np.ndarray, qhi: np.ndarray) -> Plan:
-        """Per-query dispatch decisions for one batch of range boxes."""
+        """Per-query dispatch decisions for one batch of range boxes,
+        under the ``khi.plan`` profiler span."""
+        with TraceAnnotation("khi.plan"):
+            return self._plan(qlo, qhi)
+
+    def _plan(self, qlo: np.ndarray, qhi: np.ndarray) -> Plan:
         qlo = np.ascontiguousarray(qlo, np.float32)
         qhi = np.ascontiguousarray(qhi, np.float32)
         B = qlo.shape[0]
@@ -1556,16 +1581,26 @@ class Planner:
                                              -np.inf, np.float32)])
         return qs, lo, hi
 
-    def _run_graph(self, qs, lo, hi):
-        ids, dists, hops = self._graph_fn(jnp.asarray(qs), jnp.asarray(lo),
-                                          jnp.asarray(hi))
-        return np.asarray(ids), np.asarray(dists), np.asarray(hops)
+    def _run_graph(self, qs, lo, hi, plan: Plan, pow2: bool = False):
+        """One graph program run (``pow2``: pad a split sub-batch first);
+        records ``(lanes, largest hop count)`` in ``plan.graph_runs``."""
+        with TraceAnnotation("khi.dispatch", program="graph"):
+            if pow2:
+                qs, lo, hi = self._pad_pow2(qs, lo, hi)
+            out = self._graph_fn(jnp.asarray(qs), jnp.asarray(lo),
+                                 jnp.asarray(hi))
+        ids, dists, hops = _fetch("graph", out)
+        plan.graph_runs.append((qs.shape[0], int(hops.max(initial=0))))
+        return ids, dists, hops
 
-    def _run_scan(self, qs, lo, hi):
-        ids, dists = self._scan_fn(jnp.asarray(qs), jnp.asarray(lo),
-                                   jnp.asarray(hi))
-        return (np.asarray(ids), np.asarray(dists),
-                np.zeros(qs.shape[0], np.int32))
+    def _run_scan(self, qs, lo, hi, pow2: bool = False):
+        with TraceAnnotation("khi.dispatch", program="scan"):
+            if pow2:
+                qs, lo, hi = self._pad_pow2(qs, lo, hi)
+            out = self._scan_fn(jnp.asarray(qs), jnp.asarray(lo),
+                                jnp.asarray(hi))
+        ids, dists = _fetch("scan", out)
+        return ids, dists, np.zeros(qs.shape[0], np.int32)
 
     def search(self, queries, qlo, qhi):
         """(B, d) × (B, m) × (B, m) -> (ids (B, k) int32, dists (B, k)
@@ -1584,18 +1619,21 @@ class Planner:
             ids, dists, hops = self._run_scan(queries, qlo, qhi)
             return ids, dists, hops, plan
         if not len(scan_idx):
-            ids, dists, hops = self._run_graph(queries, qlo, qhi)
+            ids, dists, hops = self._run_graph(queries, qlo, qhi, plan)
             return ids, dists, hops, plan
-        out_ids = np.full((B, k), -1, np.int32)
-        out_d = np.full((B, k), np.inf, np.float32)
-        out_h = np.zeros((B,), np.int32)
-        for idx, run in ((graph_idx, self._run_graph),
-                         (scan_idx, self._run_scan)):
-            qs, lo, hi = self._pad_pow2(queries[idx], qlo[idx], qhi[idx])
-            ids, dists, hops = run(qs, lo, hi)
-            out_ids[idx] = ids[: len(idx)]
-            out_d[idx] = dists[: len(idx)]
-            out_h[idx] = hops[: len(idx)]
+        runs = [(graph_idx, self._run_graph(queries[graph_idx],
+                                            qlo[graph_idx], qhi[graph_idx],
+                                            plan, pow2=True)),
+                (scan_idx, self._run_scan(queries[scan_idx], qlo[scan_idx],
+                                          qhi[scan_idx], pow2=True))]
+        with TraceAnnotation("khi.merge"):
+            out_ids = np.full((B, k), -1, np.int32)
+            out_d = np.full((B, k), np.inf, np.float32)
+            out_h = np.zeros((B,), np.int32)
+            for idx, (ids, dists, hops) in runs:
+                out_ids[idx] = ids[: len(idx)]
+                out_d[idx] = dists[: len(idx)]
+                out_h[idx] = hops[: len(idx)]
         return out_ids, out_d, out_h, plan
 
     # --------------------------------------------- compiled predicates (§15)
@@ -1647,9 +1685,10 @@ class Planner:
         bp = pow2_at_least(B)
         qs = queries if bp == B else np.concatenate(
             [queries, np.zeros((bp - B,) + queries.shape[1:], np.float32)])
-        ids, dd = self._mask_fn(jnp.asarray(mask), jnp.asarray(qs))
-        return (np.asarray(ids)[:B], np.asarray(dd)[:B],
-                np.zeros(B, np.int32))
+        with TraceAnnotation("khi.dispatch", program="mask"):
+            out = self._mask_fn(jnp.asarray(mask), jnp.asarray(qs))
+        ids, dd = _fetch("mask", out)
+        return ids[:B], dd[:B], np.zeros(B, np.int32)
 
     @staticmethod
     def _count_lanes(plan: Plan, lanes: dict, B: int) -> None:
@@ -1706,7 +1745,9 @@ class Planner:
             if out_ids is None:
                 out_ids, out_d = ids, dists
             else:
-                out_ids, out_d = _merge_dedup(out_ids, out_d, ids, dists, k)
+                with TraceAnnotation("khi.merge"):
+                    out_ids, out_d = _merge_dedup(out_ids, out_d, ids,
+                                                  dists, k)
         return out_ids, out_d, out_h, PredicatePlan(
             mode="boxes", n_boxes=prog.n_boxes, lanes=lanes,
             box_plans=box_plans, program=prog)
@@ -1727,18 +1768,20 @@ class Planner:
                 continue
             qs, lo, hi = self._pad_pow2(queries[idx], qlo[idx], qhi[idx])
             if m == 0:
-                ids, dists, hops = self._run_graph(qs, lo, hi)
+                ids, dists, hops = self._run_graph(qs, lo, hi, plan)
             else:
                 starts, counts, w_cap = self._build_windows(
                     plan.small_nodes, idx, qs.shape[0])
                 ids, dists, hops = self._run_windows(qs, lo, hi, starts,
                                                      counts, w_cap)
                 if m == 2:
-                    gids, gd, hops = self._run_graph(qs, lo, hi)
-                    ids, dists = _merge_dedup(
-                        gids[: len(idx)], gd[: len(idx)],
-                        ids[: len(idx)], dists[: len(idx)], k)
-            out_ids[idx] = ids[: len(idx)]
-            out_d[idx] = dists[: len(idx)]
-            out_h[idx] = hops[: len(idx)]
+                    gids, gd, hops = self._run_graph(qs, lo, hi, plan)
+                    with TraceAnnotation("khi.merge"):
+                        ids, dists = _merge_dedup(
+                            gids[: len(idx)], gd[: len(idx)],
+                            ids[: len(idx)], dists[: len(idx)], k)
+            with TraceAnnotation("khi.merge"):
+                out_ids[idx] = ids[: len(idx)]
+                out_d[idx] = dists[: len(idx)]
+                out_h[idx] = hops[: len(idx)]
         return out_ids, out_d, out_h, plan

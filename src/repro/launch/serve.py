@@ -90,9 +90,14 @@ def serve_khi(args):
     results = list(svc.serve_stream(reqs))
     dt = time.perf_counter() - t0
     snap = svc.snapshot()
+    lanes = snap["graph_lanes"]
     print(f"[serve] {len(results)} requests in {dt:.2f}s "
-          f"({len(results)/dt:.0f} QPS end-to-end; "
-          f"device {snap['device_qps'] and round(snap['device_qps'])} QPS)")
+          f"({len(results)/dt:.0f} QPS end-to-end)")
+    print(f"[serve] hop loop, warm-up included: graph_lanes={lanes} "
+          f"graph_hops={snap['graph_hops']} "
+          f"graph_lane_steps={snap['graph_lane_steps']} (hops per lane "
+          f"{snap['graph_hops'] / lanes if lanes else 0:.1f}, hop-loop "
+          f"occupancy {snap['graph_hops'] / max(snap['graph_lane_steps'], 1):.2f})")
     print(f"[serve] backend={args.backend} E={args.expand_width} "
           f"router={args.router} strategy={args.strategy} "
           f"batches={snap['batches']} scan_lanes={snap['scan_lanes']} "

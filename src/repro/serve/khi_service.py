@@ -80,6 +80,21 @@ as a full-quality hit), and all tier planners dispatch off ONE shared
 plan cache (the routing bound is tier-invariant). The SLO scheduler
 (``serve/scheduler.py``) is the component that steps requests down the
 ladder under load.
+
+**Observability** (DESIGN.md §3): ``search``, ``search_expr`` and
+``flush`` run under a ``khi.search`` profiler span
+(``jax.profiler.TraceAnnotation``, args ``seq``, ``lanes``, ``tier``)
+that holds ``khi.cache`` (result-cache hashing, lookups and puts), the
+Planner's ``khi.plan``, one ``khi.dispatch`` / ``khi.fetch`` pair per
+device program run (arg ``program``) and ``khi.merge`` (host merges);
+the graph program names its two phases with the ``khi_router`` and
+``khi_hop_loop`` scopes. ``stats`` counts the hop loop's work, beside
+``scan_lanes``: ``graph_lanes`` (real lanes that ran the graph program),
+``graph_hops`` (their hops) and ``graph_lane_steps`` (per graph program
+run, the lanes it ran, pads included, times its largest hop count: the
+lane-iterations the lockstep loop paid for). Under ``mesh=`` hops never
+reach the host, so these three do not move there. ``last_routes`` /
+``last_hops`` hold the last batch's per-lane dispatch record.
 """
 
 from __future__ import annotations
@@ -94,12 +109,13 @@ from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.delta import StreamingState
 from ..core.engine import (SCAN_BACKENDS, DeviceIndex, Planner, SearchParams,
-                           _merge_dedup, _query_one, device_put_index,
-                           resolve_scorer_pair, validate_search_params,
-                           with_quant_replica)
+                           _fetch, _merge_dedup, _query_one,
+                           device_put_index, resolve_scorer_pair,
+                           validate_search_params, with_quant_replica)
 from ..core.khi import KHIConfig, KHIIndex
 from ..core.predicate import canonical_key, compile_expr, validate_expr
 from ..core.sharded import (ShardedKHI, _merge_topk, _shard_search,
@@ -199,13 +215,20 @@ class KHIService:
         self._next_ticket = 0
         self.stats = {
             "requests": 0, "cache_hits": 0, "batches": 0, "pad_lanes": 0,
-            "device_queries": 0, "traced_buckets": set(),
-            "device_seconds": 0.0, "epoch_swaps": 0, "scan_lanes": 0,
+            "traced_buckets": set(), "epoch_swaps": 0, "scan_lanes": 0,
+            "graph_lanes": 0, "graph_hops": 0, "graph_lane_steps": 0,
             "inserts": 0, "deletes": 0, "compactions": 0,
             "ingest_seconds": 0.0, "compact_seconds": 0.0,
             "tier_lanes": collections.Counter(),
             "predicate_lanes": collections.Counter(),
         }
+        # per-lane dispatch record of the last batch ``_answer`` served
+        # (``search``, each box of ``search_expr``, ``flush``): each lane's
+        # route ("cache" for a hit, else ``_routes``) and its hops (-1
+        # where the host never sees them: under mesh=)
+        self.last_routes = np.zeros((0,), "U6")
+        self.last_hops = np.zeros((0,), np.int32)
+        self._seq = 0               # khi.search span sequence number
         # set to stats["predicate_lanes"] for the duration of a compiled-
         # predicate run so the dispatch chokepoints attribute their device
         # lanes to it (DESIGN.md §15); None outside search_expr
@@ -350,6 +373,9 @@ class KHIService:
         return fn
 
     def _build_search_fn(self, tier: int = 0):
+        # Each closure takes one bucket-padded host batch and returns host
+        # (ids, dists, hops, plan): hops (bucket,) is None where the host
+        # never sees it (mesh=), plan the Planner's Plan or None.
         # Every branch reads ``self.index`` at CALL time (not build time):
         # a streaming delete installs a functionally-updated pytree of
         # identical shapes, which the jitted programs must pick up without
@@ -370,7 +396,7 @@ class KHIService:
                                         on_undersized=self._on_undersized,
                                         interpret=self._interpret)
             self._hlo_fns[tier] = self._jit_hlo("collective", fn)
-            return lambda q, lo, hi: fn(self.index, q, lo, hi)
+            return self._device_run("collective", fn)
         if p.strategy != "graph":
             # planner-backed path (DESIGN.md §10): per-lane dispatch to the
             # graph engine or the exact brute scan, single or sharded —
@@ -391,28 +417,28 @@ class KHIService:
             self._planners[tier] = planner
 
             def run(q, lo, hi):
-                ids, dists, _hops, plan = planner.search(
-                    np.asarray(q), np.asarray(lo), np.asarray(hi))
+                ids, dists, hops, plan = planner.search(q, lo, hi)
                 self.stats["scan_lanes"] += int(plan.use_scan.sum())
                 if self._pred_lanes is not None:
                     # compiled-predicate observability (§15): fold this
                     # box's per-lane dispatch into predicate_lanes
-                    Planner._count_lanes(plan, self._pred_lanes,
-                                         np.asarray(q).shape[0])
-                return ids, dists
+                    Planner._count_lanes(plan, self._pred_lanes, q.shape[0])
+                return ids, dists, hops, plan
 
             return run
+        # strategy="graph": every lane runs the graph program, which
+        # returns per-lane hops (a lane's max over shards, as the
+        # Planner's sharded program does)
         if not self._sharded:
             @jax.jit
             def single(di: DeviceIndex, q, qlo, qhi):
                 fn = functools.partial(_query_one, p=p, scorer=scorer,
                                        exact_scorer=exact)
-                ids, dists, _ = jax.vmap(
+                return jax.vmap(
                     lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
-                return ids, dists
 
             self._hlo_fns[tier] = self._jit_hlo("graph", single)
-            return lambda q, lo, hi: single(self.index, q, lo, hi)
+            return self._device_run("graph", single)
 
         n_shards = self.index.num_shards
 
@@ -421,11 +447,23 @@ class KHIService:
             def per_shard(di, off):
                 return _shard_search(di, off, n_shards, q, qlo, qhi,
                                      p, scorer, exact_scorer=exact)
-            gids, dists, _ = jax.vmap(per_shard)(skhi.di, skhi.offsets)
-            return _merge_topk(gids, dists, p.k)
+            gids, dists, hops = jax.vmap(per_shard)(skhi.di, skhi.offsets)
+            return _merge_topk(gids, dists, p.k) + (jnp.max(hops, axis=0),)
 
         self._hlo_fns[tier] = self._jit_hlo("graph", fanout)
-        return lambda q, lo, hi: fanout(self.index, q, lo, hi)
+        return self._device_run("graph", fanout)
+
+    def _device_run(self, program: str, fn):
+        """Host closure over a whole-batch program ``fn(index, q, lo, hi)``
+        that returns (ids, dists) or (ids, dists, hops), under the
+        ``khi.dispatch`` / ``khi.fetch`` profiler spans."""
+        def run(q, lo, hi):
+            with TraceAnnotation("khi.dispatch", program=program):
+                out = fn(self.index, jnp.asarray(q), jnp.asarray(lo),
+                         jnp.asarray(hi))
+            ids, dists, *hops = _fetch(program, out)
+            return ids, dists, (hops[0] if hops else None), None
+        return run
 
     def _jit_hlo(self, name: str, fn):
         """batch -> {name: compiled HLO text} for a program called as
@@ -488,9 +526,10 @@ class KHIService:
 
     # ----------------------------------------------------------- device run
     def _run_device(self, qs: np.ndarray, los: np.ndarray,
-                    his: np.ndarray, tier: int = 0
-                    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Pad one micro-batch to its bucket, search at ``tier``, unpad."""
+                    his: np.ndarray, tier: int = 0):
+        """Pad one micro-batch to its bucket, search at ``tier``, unpad:
+        -> (ids, dists, hops, routes), each (b,) per lane; hops is None
+        under mesh=."""
         b = qs.shape[0]
         bucket = self._bucket(b)
         pad = bucket - b
@@ -501,24 +540,19 @@ class KHIService:
                 [los, np.full((pad, self.m), np.inf, np.float32)])
             his = np.concatenate(
                 [his, np.full((pad, self.m), -np.inf, np.float32)])
-        t0 = time.perf_counter()
         search = self._search if tier == 0 else self._get_search_fn(tier)
-        ids, dists = search(jnp.asarray(qs), jnp.asarray(los),
-                            jnp.asarray(his))
-        ids, dists = jax.block_until_ready((ids, dists))
-        ids, dists = np.asarray(ids), np.asarray(dists)
+        ids, dists, hops, plan = search(qs, los, his)
         if self._stream is not None:
             # windowed merge (DESIGN.md §11): fold the per-shard delta
             # scans into the epoch results on the bucket-padded batch (the
             # delta scan traces per bucket shape too; pad lanes carry the
             # empty box and contribute nothing), then unpad. Ids become
             # stable int64 ext ids here.
-            ids, dists = self._stream.merge(ids, dists, qs, los, his,
-                                            self.params.k)
-        self.stats["device_seconds"] += time.perf_counter() - t0
+            with TraceAnnotation("khi.merge"):
+                ids, dists = self._stream.merge(ids, dists, qs, los, his,
+                                                self.params.k)
         self.stats["batches"] += 1
         self.stats["pad_lanes"] += pad
-        self.stats["device_queries"] += bucket
         self.stats["traced_buckets"].add(bucket)
         self.stats["tier_lanes"][tier] += b
         if self._pred_lanes is not None \
@@ -526,7 +560,38 @@ class KHIService:
             # strategy="graph" has no per-lane Plan — every device lane of
             # a predicate box (pads included) is a graph lane (§15)
             self._pred_lanes["graph"] += bucket
-        return ids[:b], dists[:b]
+        routes = self._routes(plan, bucket, hops is None)[:b]
+        if hops is not None:
+            hops = hops[:b]
+            self._count_graph(plan, hops, routes, bucket)
+        return ids[:b], dists[:b], hops, routes
+
+    @staticmethod
+    def _routes(plan, bucket: int, mesh: bool) -> np.ndarray:
+        """Each device lane's route: "mesh" where the dispatch ran inside
+        the collective program, else "graph", "scan", or under
+        strategy="hybrid" "window" (windows only) or "mixed" (graph walk
+        plus windows)."""
+        if mesh:
+            return np.full(bucket, "mesh")
+        if plan is None:
+            return np.full(bucket, "graph")
+        if plan.mode is not None:
+            return np.array(["graph", "window", "mixed"])[plan.mode]
+        return np.where(plan.use_scan, "scan", "graph")
+
+    def _count_graph(self, plan, hops: np.ndarray, routes: np.ndarray,
+                     bucket: int) -> None:
+        """Hop-loop counters of one device batch, ``hops`` and ``routes``
+        of its real lanes: graph_lanes, graph_hops (scan and window lanes
+        carry 0), and graph_lane_steps — for each graph program run, the
+        lanes it ran (pads included) times its largest hop count."""
+        self.stats["graph_lanes"] += int(
+            np.isin(routes, ("graph", "mixed")).sum())
+        self.stats["graph_hops"] += int(hops.sum())
+        runs = plan.graph_runs if plan is not None else [
+            (bucket, int(hops.max(initial=0)))]
+        self.stats["graph_lane_steps"] += sum(n * h for n, h in runs)
 
     # -------------------------------------------------------------- serving
     def _answer(self, queries: np.ndarray, lo: np.ndarray,
@@ -534,7 +599,9 @@ class KHIService:
                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Cache-aware core: -> (ids (B, k), dists (B, k), hit (B,) bool).
         Batches larger than the top bucket are chunked. ``tier`` selects
-        the degradation-ladder params (DESIGN.md §13; 0 = full quality)."""
+        the degradation-ladder params (DESIGN.md §13; 0 = full quality).
+        Leaves the batch's per-lane dispatch record in ``last_routes`` /
+        ``last_hops``."""
         queries = np.ascontiguousarray(queries, np.float32)
         lo = np.ascontiguousarray(lo, np.float32)
         hi = np.ascontiguousarray(hi, np.float32)
@@ -545,30 +612,39 @@ class KHIService:
         out_ids = np.full((B, k), -1, id_dtype)
         out_d = np.full((B, k), np.inf, np.float32)
         hit_mask = np.zeros((B,), bool)
+        routes = np.full(B, "cache", "U6")
+        lane_hops = np.zeros(B, np.int32)
 
         # skip per-request hashing entirely when the cache is disabled —
         # blake2b over d=768 query bytes is measurable on the hot path
         caching = self.config.cache_size > 0
-        keys = [self._key(queries[i], lo[i], hi[i], tier) if caching else None
-                for i in range(B)]
-        miss: List[int] = []
-        for i, key in enumerate(keys):
-            hit = self._cache_get(key) if caching else None
-            if hit is not None:
-                out_ids[i], out_d[i] = hit
-                hit_mask[i] = True
-                self.stats["cache_hits"] += 1
-            else:
-                miss.append(i)
+        miss: List[int] = list(range(B))
+        if caching:
+            with TraceAnnotation("khi.cache"):
+                keys = [self._key(queries[i], lo[i], hi[i], tier)
+                        for i in range(B)]
+                miss = []
+                for i, key in enumerate(keys):
+                    hit = self._cache_get(key)
+                    if hit is not None:
+                        out_ids[i], out_d[i] = hit
+                        hit_mask[i] = True
+                        self.stats["cache_hits"] += 1
+                    else:
+                        miss.append(i)
 
         for c0 in range(0, len(miss), self.config.max_batch):
             chunk = miss[c0:c0 + self.config.max_batch]
-            ids, dists = self._run_device(queries[chunk], lo[chunk],
-                                          hi[chunk], tier)
-            for j, i in enumerate(chunk):
-                out_ids[i], out_d[i] = ids[j], dists[j]
-                if caching:
-                    self._cache_put(keys[i], ids[j], dists[j])
+            ids, dists, hops, chunk_routes = self._run_device(
+                queries[chunk], lo[chunk], hi[chunk], tier)
+            routes[chunk] = chunk_routes
+            lane_hops[chunk] = -1 if hops is None else hops
+            out_ids[chunk], out_d[chunk] = ids, dists
+            if caching:
+                with TraceAnnotation("khi.cache"):
+                    for j, i in enumerate(chunk):
+                        self._cache_put(keys[i], ids[j], dists[j])
+        self.last_routes, self.last_hops = routes, lane_hops
         return out_ids, out_d, hit_mask
 
     def search(self, queries: np.ndarray, lo: np.ndarray,
@@ -582,8 +658,17 @@ class KHIService:
             raise ValueError(f"tier must be in [0, {len(self._tier_params)})"
                              f", got {tier} (install ladders via tiers= / "
                              f"set_tiers)")
-        ids, dists, _ = self._answer(queries, lo, hi, tier)
+        with self._search_span(len(queries), tier):
+            ids, dists, _ = self._answer(queries, lo, hi, tier)
         return ids, dists
+
+    def _search_span(self, lanes: int, tier: int) -> TraceAnnotation:
+        """The ``khi.search`` profiler span around one front-door call;
+        its ``seq`` names the batch every inner ``khi.*`` span belongs
+        to."""
+        self._seq += 1
+        return TraceAnnotation("khi.search", seq=self._seq, lanes=lanes,
+                               tier=tier)
 
     # ------------------------------------------- compiled predicates (§15)
     def _pred_planner(self, tier: int) -> Planner:
@@ -628,6 +713,11 @@ class KHIService:
                 "boxes as plain search() calls (DESIGN.md §15)")
         validate_expr(expr, self.m)
         queries = np.ascontiguousarray(queries, np.float32)
+        with self._search_span(queries.shape[0], tier):
+            return self._search_expr(queries, expr, tier)
+
+    def _search_expr(self, queries: np.ndarray, expr, tier: int
+                     ) -> Tuple[np.ndarray, np.ndarray]:
         B, k = queries.shape[0], self.params.k
         p = self._tier_params[tier]
         prog = compile_expr(expr, self.m, box_budget=p.box_budget)
@@ -662,9 +752,10 @@ class KHIService:
                 else:
                     # disjoint cover: no row appears under two boxes, so
                     # best-dist-per-id dedup only collapses (-1, inf) pads
-                    out_ids, out_d = _merge_dedup(out_ids, out_d, ids,
-                                                  dists, k,
-                                                  out_dtype=id_dtype)
+                    with TraceAnnotation("khi.merge"):
+                        out_ids, out_d = _merge_dedup(out_ids, out_d, ids,
+                                                      dists, k,
+                                                      out_dtype=id_dtype)
         finally:
             self._pred_lanes = None
         return out_ids, out_d
@@ -692,7 +783,8 @@ class KHIService:
             qs = np.stack([batch[j].query for j in box_idx]).astype(np.float32)
             los = np.stack([batch[j].lo for j in box_idx]).astype(np.float32)
             his = np.stack([batch[j].hi for j in box_idx]).astype(np.float32)
-            ids, dists, hit = self._answer(qs, los, his)
+            with self._search_span(len(box_idx), 0):
+                ids, dists, hit = self._answer(qs, los, his)
             for i, j in enumerate(box_idx):
                 results[j] = Result(ids=ids[i], dists=dists[i],
                                     cached=bool(hit[i]))
@@ -853,8 +945,6 @@ class KHIService:
                                 in sorted(s["predicate_lanes"].items())}
         s["cache_entries"] = len(self._cache)
         s["epoch"] = self.epoch
-        dq, ds = s["device_queries"], s["device_seconds"]
-        s["device_qps"] = (dq / ds) if ds > 0 else None
         if self._stream is not None:
             s["streaming"] = True
             s["n_live"] = self._stream.n_live
