@@ -739,7 +739,10 @@ def _lex_topk(ids: jax.Array, dists: jax.Array,
 def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
                p: SearchParams, scorer: Scorer,
                exact_scorer: Optional[Scorer] = None
-               ) -> tuple[jax.Array, jax.Array, jax.Array]:
+               ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One query's two-phase search -> (ids (k,), dists (k,), hops (),
+    router chunks per tree level (H,); ``router_chunks`` reduces the
+    last over a vmapped batch)."""
     n = di.n
     H, M = di.nbrs.shape[1], di.nbrs.shape[2]
     HM = H * M
@@ -751,7 +754,7 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     # The two named scopes are op_name metadata only: they name the
     # phases in a profile of every graph program (DESIGN.md §3)
     with jax.named_scope("khi_router"):
-        entries, _ = resolve_router(p.router)(di, qlo, qhi, p)
+        entries, _, chunks = resolve_router(p.router)(di, qlo, qhi, p)
     e_valid = entries >= 0
     e_dist = scorer.score(di, q, qlo, qhi, entries)
 
@@ -825,7 +828,7 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
         pool, visited, seen, hops = jax.lax.while_loop(
             cond, body, (pool0, visited, seen0, jnp.int32(0)))
     if exact_scorer is None:
-        return pool.ids[: p.k], pool.dists[: p.k], hops
+        return pool.ids[: p.k], pool.dists[: p.k], hops, chunks
     # quantized rerank tail (DESIGN.md §12): the loop above ranked the
     # pool on compressed-replica distances, so the quantized order near
     # the k boundary may invert vs f32. Rescore the top
@@ -836,7 +839,16 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     cand = pool.ids[:rr]
     exact_d = exact_scorer.score(di, q, qlo, qhi, cand)
     ids_k, dists_k = _lex_topk(cand, exact_d, p.k)
-    return ids_k, dists_k, hops
+    return ids_k, dists_k, hops, chunks
+
+
+def router_chunks(chunks: jax.Array) -> jax.Array:
+    """(..., H) per-lane router chunks per level -> () int32: the chunk
+    iterations the level router's loops ran for the whole vmapped
+    program. A level's lockstep loop runs to its largest count over the
+    lanes (and shards), so this is that largest count, summed over the
+    levels."""
+    return jnp.sum(jnp.max(chunks.reshape(-1, chunks.shape[-1]), axis=0))
 
 
 def make_search_fn(p: SearchParams, *, dist_fn=None, donate: bool = False,
@@ -864,7 +876,8 @@ def make_search_fn(p: SearchParams, *, dist_fn=None, donate: bool = False,
     def search(di: DeviceIndex, queries, qlo, qhi):
         fn = functools.partial(_query_one, p=p, scorer=scorer,
                                exact_scorer=exact)
-        return jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(queries, qlo, qhi)
+        return jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(
+            queries, qlo, qhi)[:3]
 
     return search
 
@@ -1096,7 +1109,9 @@ class Plan:
     ``graph_runs`` is filled by ``Planner.search``: one ``(lanes, hops)``
     pair per graph program run that served the batch — the lanes it ran,
     pow2 pads included, and its largest hop count. Their product is the
-    lane-iterations the lockstep hop loop paid for."""
+    lane-iterations the lockstep hop loop paid for. ``router_chunks``
+    sums, over the same runs, the chunk iterations the level router's
+    loops ran (``router_chunks()``)."""
 
     card: np.ndarray       # (B,) int64/int32
     use_scan: np.ndarray   # (B,) bool
@@ -1106,6 +1121,7 @@ class Plan:
     n_windows: Optional[np.ndarray] = None    # (B,) int64, hybrid only
     small_nodes: Optional[list] = None        # per-shard (B, P) bool
     graph_runs: list = dataclasses.field(default_factory=list)
+    router_chunks: int = 0
 
 
 @dataclasses.dataclass
@@ -1386,8 +1402,9 @@ class Planner:
             def graph(di, q, qlo, qhi):
                 fn = functools.partial(_query_one, p=p, scorer=scorer,
                                        exact_scorer=exact)
-                return jax.vmap(lambda qq, lo, hi: fn(di, qq, lo, hi))(
-                    q, qlo, qhi)
+                ids, dists, hops, chunks = jax.vmap(
+                    lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
+                return ids, dists, hops, router_chunks(chunks)
             self._programs["graph"] = (graph, lambda: (self.index,))
             return lambda q, qlo, qhi: graph(self.index, q, qlo, qhi)
 
@@ -1399,9 +1416,10 @@ class Planner:
             def per_shard(di, off):
                 return _shard_search(di, off, S, q, qlo, qhi, p, scorer,
                                      exact_scorer=exact)
-            gids, dists, hops = jax.vmap(per_shard)(skhi.di, skhi.offsets)
+            gids, dists, hops, chunks = jax.vmap(per_shard)(skhi.di,
+                                                            skhi.offsets)
             mi, md = _merge_topk(gids, dists, p.k)
-            return mi, md, jnp.max(hops, axis=0)
+            return mi, md, jnp.max(hops, axis=0), router_chunks(chunks)
 
         self._programs["graph"] = (graph_sharded, lambda: (self.index,))
         return lambda q, qlo, qhi: graph_sharded(self.index, q, qlo, qhi)
@@ -1583,14 +1601,16 @@ class Planner:
 
     def _run_graph(self, qs, lo, hi, plan: Plan, pow2: bool = False):
         """One graph program run (``pow2``: pad a split sub-batch first);
-        records ``(lanes, largest hop count)`` in ``plan.graph_runs``."""
+        records ``(lanes, largest hop count)`` in ``plan.graph_runs`` and
+        adds its router chunks to ``plan.router_chunks``."""
         with TraceAnnotation("khi.dispatch", program="graph"):
             if pow2:
                 qs, lo, hi = self._pad_pow2(qs, lo, hi)
             out = self._graph_fn(jnp.asarray(qs), jnp.asarray(lo),
                                  jnp.asarray(hi))
-        ids, dists, hops = _fetch("graph", out)
+        ids, dists, hops, chunks = _fetch("graph", out)
         plan.graph_runs.append((qs.shape[0], int(hops.max(initial=0))))
+        plan.router_chunks += int(chunks)
         return ids, dists, hops
 
     def _run_scan(self, qs, lo, hi, pow2: bool = False):

@@ -17,7 +17,7 @@ from . import beam
 from .khi import KHIIndex
 
 __all__ = ["Predicate", "range_filter", "range_filter_level", "recons_nbr",
-           "estimate_cardinality", "query", "brute_force",
+           "estimate_cardinality", "frontier_widths", "query", "brute_force",
            "brute_force_expr", "StreamingOracle"]
 
 
@@ -233,6 +233,25 @@ def estimate_cardinality(index: KHIIndex, pred: Predicate,
     oracle the bound is validated against)."""
     if exact:
         return int(pred.matches(index.attrs).sum())
+    return _level_sweep(index, pred)[1]
+
+
+def frontier_widths(index: KHIIndex, pred: Predicate,
+                    frontier_cap: Optional[int] = None) -> List[int]:
+    """Numpy twin of the level router's live frontier: the nodes each
+    tree level sweeps for ``pred``, from the root's level down to the
+    last non-empty one. ``frontier_cap`` applies the device frontier's
+    overflow clamp (a level keeps its first ``frontier_cap`` children in
+    tree order). A level of width w costs ceil(w / C) chunks of the
+    device sweep (``router._sweep``)."""
+    return _level_sweep(index, pred, frontier_cap)[0]
+
+
+def _level_sweep(index: KHIIndex, pred: Predicate,
+                 frontier_cap: Optional[int] = None
+                 ) -> Tuple[List[int], int]:
+    """(frontier width per level, scanned-antichain count sum) of the
+    breadth-first level sweep."""
     t = index.tree
     m = index.m
     full = (1 << m) - 1
@@ -245,8 +264,10 @@ def estimate_cardinality(index: KHIIndex, pred: Predicate,
             D0 |= 1 << i
 
     card = 0
+    widths: List[int] = []
     frontier: List[Tuple[int, int]] = [(root, D0)]
     while frontier:
+        widths.append(len(frontier))
         nxt: List[Tuple[int, int]] = []
         for p, D in frontier:
             D |= int(t.bl[p])
@@ -265,8 +286,8 @@ def estimate_cardinality(index: KHIIndex, pred: Predicate,
                     nxt.append((pc, D | (1 << dsp)))
                 else:
                     nxt.append((pc, D))
-        frontier = nxt
-    return card
+        frontier = nxt[:frontier_cap]
+    return widths, card
 
 
 def recons_nbr(index: KHIIndex, o: int, pred: Predicate, c_n: int,

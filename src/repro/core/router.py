@@ -5,23 +5,30 @@
 Routing finds up to ``c_e`` entry points in O_B by walking the attribute
 partition tree. Two device implementations share one contract
 (``route(di, qlo, qhi, p) -> ((c_e,) int32 entry ids, -1 padded, in DFS
-order; () int32 in-range cardinality bound)``) and return **identical
-entry vectors** (pinned by tests/test_router.py):
+order; () int32 in-range cardinality bound; (H,) int32 chunks each tree
+level swept)``) and return **identical entry vectors** (pinned by
+tests/test_router.py):
 
   * ``route_dfs`` — the legacy per-query stack DFS ``lax.while_loop``
-    (one node pop per iteration). Inside the vmapped batch every lane
-    pays the slowest lane's pop count: the while_loop is lockstep, so a
-    single deep query serializes the whole batch.
-  * ``route_level_sync`` — the production router: a fixed
-    ``lax.fori_loop`` over tree **levels** (height is O(log n), Lemma 1)
-    with a per-query fixed-width frontier of (node, D-bitmask) pairs.
-    Every level classifies its whole frontier at once, then entry-scans
-    its scanned nodes in DFS-rank order, ``c_e`` at a time and
-    ``_SCAN_STEP`` objects per node per step, until no further node can
-    enter the answer — and the level loop's trip count is the tree
-    height, identical for every lane of the batch. (A whole-frontier
-    ``(F, scan_budget)`` gather grows with n twice over: at one 1M-row
-    shard F is ~4.3e5 nodes and scan_budget ~5e4 rows.)
+    (one node pop per iteration; it sweeps no chunks). Inside the
+    vmapped batch every lane pays the slowest lane's pop count: the
+    while_loop is lockstep, so a single deep query serializes the whole
+    batch.
+  * ``route_level_sync`` — the production router: a ``lax.scan`` over
+    tree **levels** (height is O(log n), Lemma 1) with a per-query
+    frontier of (node, D-bitmask) pairs, ``frontier_cap`` slots wide.
+    The frontier is compacted: its live nodes sit in ``[0, live)``, in
+    left-to-right tree order. Each level sweeps only that live prefix,
+    in chunks of C = min(``_CHUNK``, ``frontier_cap``) slots: a chunk
+    classifies its nodes, compacts their children into the next
+    frontier and entry-scans its scanned nodes in DFS-rank order,
+    ``c_e`` at a time and ``_SCAN_STEP`` objects per node per step,
+    until none of them can enter the answer. Under ``vmap`` a level's
+    chunk loop runs to the batch's largest ``ceil(live / C)``, so a
+    batch pays, per level, for its widest lane's live frontier and not
+    for the tree's widest level. (A whole-frontier ``(F, scan_budget)``
+    gather grows with n twice over: at one 1M-row shard F is ~4.3e5
+    nodes and scan_budget ~5e4 rows.)
 
 Why the two return the same entries: the DFS collects entries in pop
 order (right child pushed last, popped first — right-first pre-order)
@@ -32,9 +39,13 @@ is traversal-order independent, and scanned nodes form an antichain
 pre-order over them exactly **descending range end**. The level-sync
 router therefore tags each candidate entry with the key
 ``n - (start + count)``, keeps the ``c_e`` smallest keys across the
-sweep (a sorted running merge per level), and returns them ascending:
+sweep (a sorted running merge per chunk), and returns them ascending:
 the same entries, in the same order, as the DFS with its early stop
-(the stop only ever drops larger keys). The numpy twin is
+(the stop only ever drops larger keys). The c_e smallest keys of a set
+do not depend on the order it is visited in, so the chunking leaves
+the entries as they are; nor does it move a child's frontier slot,
+which is a running offset over the level's earlier chunks plus the
+chunk's own exclusive cumsum: the level-wide cumsum. The numpy twin is
 ``query_ref.range_filter_level``.
 
 The frontier width is bounded by ``SearchParams.frontier_cap`` with the
@@ -55,9 +66,9 @@ traversal they already do; it is the planner's selectivity estimate.
 Caveat: the DFS early-stops after ``c_e`` entries, so *its* sum covers
 only the visited prefix of the antichain and is NOT a bound — the
 planner therefore requires ``router="level"`` (the sweep always runs
-all levels). ``route_level_card`` is the estimate-only form: same
-traversal, no entry scans (it skips the per-level entry-scan gathers,
-the expensive part of routing).
+all levels). ``route_level_card`` is the estimate-only form and
+``route_level_windows`` the hybrid form: the same chunked traversal
+(``_sweep``), with no entry scans (the expensive part of routing).
 """
 
 from __future__ import annotations
@@ -83,6 +94,13 @@ _I32_MAX = np.iinfo(np.int32).max
 # largest scannable node, ~5e4 rows at a 1M-row shard) only bounds how far
 # the scan may go
 _SCAN_STEP = 128
+# frontier slots per chunk of the level sweep: a level costs
+# ceil(live / _CHUNK) chunk iterations, so a small chunk pays the loop's
+# per-iteration cost more often and a large one sweeps more dead slots.
+# On a v5e, 1024-4096 run the router fastest; 16384 keeps the graph
+# benchmark cells under the rate their query pool can measure (PERF.md
+# §6, §7)
+_CHUNK = 16384
 
 
 def _root_D0(di, qlo, qhi, m: int) -> jax.Array:
@@ -97,10 +115,10 @@ def _root_D0(di, qlo, qhi, m: int) -> jax.Array:
 
 def route_dfs(di, qlo: jax.Array, qhi: jax.Array, p):
     """Returns (entry-point object ids (c_e,), -1 padded, DFS order;
-    () int32 sum of scanned-node counts). The DFS early-stops after c_e
-    entries, so its count sum covers only the visited antichain prefix —
-    NOT an |O_B| bound (module docstring); the planner requires the
-    level router for that."""
+    () int32 sum of scanned-node counts; (H,) int32 zeros: no chunks).
+    The DFS early-stops after c_e entries, so its count sum covers only
+    the visited antichain prefix — NOT an |O_B| bound (module
+    docstring); the planner requires the level router for that."""
     m = di.attrs.shape[1]
     full = (1 << m) - 1
     S = p.stack_cap
@@ -180,7 +198,7 @@ def route_dfs(di, qlo: jax.Array, qhi: jax.Array, p):
         return (stack_node, stack_D, sp, entries, n_e, card, steps + 1)
 
     state = jax.lax.while_loop(cond, body, state)
-    return state[3], state[5]
+    return state[3], state[5], jnp.zeros((di.nbrs.shape[1],), jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -197,20 +215,13 @@ def _require_frontier(F: int) -> None:
             "arbitrary fixed width would silently drop router branches.")
 
 
-def _frontier0(di, qlo, qhi, m: int, F: int):
-    """Width-F frontier holding the root and its seed D."""
-    return (jnp.full((F,), -1, jnp.int32).at[0].set(di.root),
-            jnp.zeros((F,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m)))
-
-
-def _frontier_step(di, qlo, qhi, F: int, full: int, fnode, fD):
-    """One level of the sweep, shared by the entry router and the
-    card-only estimator: classify the frontier (scanned antichain nodes
-    vs nodes to expand) and compact the children into the next frontier
-    (overflow clamps at F, the documented ``frontier_cap`` bound).
-    Returns (node (F,) leaf-safe ids, do_scan (F,) bool, fnode', fD')."""
-    alive = fnode >= 0
-    node = jnp.maximum(fnode, 0)
+def _frontier_step(di, qlo, qhi, full: int, fnode, fD, alive):
+    """One chunk of a level: classify its frontier slots (scanned
+    antichain nodes vs nodes to expand) and list their children in slot
+    order, left child first. Returns (node (C,) leaf-safe ids,
+    do_scan (C,) bool, and the (2C,) candidate children: node, D,
+    valid)."""
+    node = jnp.where(alive, fnode, 0)
     D = jnp.where(alive, fD | di.bl[node], 0)
     is_full = D == full
     is_leaf = di.left[node] < 0
@@ -236,27 +247,77 @@ def _frontier_step(di, qlo, qhi, F: int, full: int, fnode, fD):
     cl, cr = di.left[node], di.right[node]
     vl, Dl = child(cl)
     vr, Dr = child(cr)
-    cand_node = jnp.stack([cl, cr], axis=1).reshape(2 * F)
-    cand_D = jnp.stack([Dl, Dr], axis=1).reshape(2 * F)
-    cand_valid = jnp.stack([vl, vr], axis=1).reshape(2 * F)
-    pos = jnp.cumsum(cand_valid) - cand_valid        # exclusive
-    slot = jnp.where(cand_valid, pos, F)             # F+: overflow clamp
-    fnode2 = jnp.full((F,), -1, jnp.int32).at[slot].set(cand_node,
-                                                        mode="drop")
-    fD2 = jnp.zeros((F,), jnp.int32).at[slot].set(cand_D, mode="drop")
-    return node, do_scan, fnode2, fD2
+    C2 = 2 * fnode.shape[0]
+    return (node, do_scan, jnp.stack([cl, cr], axis=1).reshape(C2),
+            jnp.stack([Dl, Dr], axis=1).reshape(C2),
+            jnp.stack([vl, vr], axis=1).reshape(C2))
 
 
-def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
-    """Returns (entry-point object ids (c_e,), -1 padded, DFS order;
-    () int32 in-range cardinality bound — the full-antichain count sum,
-    module docstring). The DFS-rank key makes the two routers' entry
-    vectors agree."""
+def _sweep(di, qlo, qhi, p, visit: Callable, acc0):
+    """The level sweep shared by the three level routers (module
+    docstring): a ``lax.scan`` over the H tree levels, each a
+    ``while_loop`` over the live prefix ``[0, live)`` of the frontier in
+    chunks of C = min(_CHUNK, frontier_cap) slots. A chunk classifies
+    its slots, calls ``visit(node (C,), do_scan (C,), acc) -> acc`` and
+    compacts its children into the next frontier at a running offset
+    plus the chunk's own exclusive cumsum: the slot the level-wide
+    cumsum gives, so the next frontier, and its overflow clamp at
+    ``frontier_cap``, are those of a whole-frontier sweep.
+
+    Returns (acc, (H,) int32 chunks each level ran for this lane); under
+    ``vmap`` a level's loop runs to the batch's largest count, which
+    ``engine.router_chunks`` reads back."""
     F = p.frontier_cap
     _require_frontier(F)
     m = di.attrs.shape[1]
     full = (1 << m) - 1
     H = di.nbrs.shape[1]          # tree levels == path height (tree.py)
+    C = min(_CHUNK, F)
+    Fp = -(-F // C) * C           # whole chunks: a chunk slice never clamps
+    slot = jnp.arange(C)
+
+    def level(st, _):
+        fnode, fD, live, gnode, gD, acc = st
+        nc = (live + C - 1) // C
+
+        def more(c):
+            return c[0] < nc
+
+        def chunk(c):
+            i, gnode, gD, nxt, acc = c
+            base = i * C
+            node, do_scan, cn, cD, cv = _frontier_step(
+                di, qlo, qhi, full,
+                jax.lax.dynamic_slice(fnode, (base,), (C,)),
+                jax.lax.dynamic_slice(fD, (base,), (C,)),
+                base + slot < live)
+            pos = nxt + jnp.cumsum(cv) - cv              # exclusive
+            dst = jnp.where(cv & (pos < F), pos, Fp)     # Fp: dropped (clamp)
+            gnode = gnode.at[dst].set(cn, mode="drop")
+            gD = gD.at[dst].set(cD, mode="drop")
+            return (i + 1, gnode, gD, nxt + jnp.sum(cv),
+                    visit(node, do_scan, acc))
+
+        _, gnode, gD, nxt, acc = jax.lax.while_loop(
+            more, chunk, (jnp.int32(0), gnode, gD, jnp.int32(0), acc))
+        # the next level reads gnode; this level's buffers take its
+        # children in turn
+        return (gnode, gD, jnp.minimum(nxt, F), fnode, fD, acc), nc
+
+    fnode = jnp.zeros((Fp,), jnp.int32).at[0].set(di.root)
+    fD = jnp.zeros((Fp,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m))
+    st, chunks = jax.lax.scan(
+        level, (fnode, fD, jnp.int32(1), jnp.zeros_like(fnode),
+                jnp.zeros_like(fD), acc0), None, length=H)
+    return st[5], chunks
+
+
+def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
+    """Returns (entry-point object ids (c_e,), -1 padded, DFS order;
+    () int32 in-range cardinality bound — the full-antichain count sum,
+    module docstring; (H,) int32 chunks per level). The DFS-rank key
+    makes the two routers' entry vectors agree."""
+    _require_frontier(p.frontier_cap)
     n = di.order.shape[0]
     SB = p.scan_budget
     W = min(SB, _SCAN_STEP)
@@ -287,28 +348,25 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
         return jax.lax.while_loop(
             more, step, (jnp.int32(0), jnp.full(sj.shape, -1, jnp.int32)))[1]
 
-    T = min(F, p.c_e)             # nodes entry-scanned per chunk
-
-    def level(_lvl, st):
-        fnode, fD, keys, ents, card = st
-        node, do_scan, fnode, fD = _frontier_step(di, qlo, qhi, F, full,
-                                                  fnode, fD)
+    def visit(node, do_scan, acc):
+        keys, ents, card = acc
         s = di.start[node]
         cnt = di.count[node]
         card = card + jnp.sum(jnp.where(do_scan, cnt, 0))
-        # DFS-rank keys of the level's scanned nodes: right-first
+        # DFS-rank keys of the chunk's scanned nodes: right-first
         # pre-order over the scanned antichain == descending range end
         # (module docstring)
         cand = jnp.where(do_scan, n - (s + cnt), _I32_MAX)
+        T = min(cand.shape[0], p.c_e)      # nodes entry-scanned per step
 
         # ---- entry scans in key order, T nodes at a time, while a
         # scanned node could still enter the running c_e smallest keys:
-        # the same entries as scanning the whole level at once
+        # the same entries as scanning the whole sweep at once
         def pending(c):
             cand, keys, _ = c
             return jnp.any(cand < keys[-1])
 
-        def scan_chunk(c):
+        def scan_step(c):
             cand, keys, ents = c
             neg, j = jax.lax.top_k(-cand, T)
             live = -neg < keys[-1]
@@ -319,39 +377,27 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
             srt = jnp.argsort(allk, stable=True)[: p.c_e]
             return cand.at[j].set(_I32_MAX), allk[srt], alle[srt]
 
-        _, keys, ents = jax.lax.while_loop(pending, scan_chunk,
+        _, keys, ents = jax.lax.while_loop(pending, scan_step,
                                            (cand, keys, ents))
-        return fnode, fD, keys, ents, card
+        return keys, ents, card
 
-    st = jax.lax.fori_loop(
-        0, H, level, (*_frontier0(di, qlo, qhi, m, F),
-                      jnp.full((p.c_e,), _I32_MAX, jnp.int32),
-                      jnp.full((p.c_e,), -1, jnp.int32), jnp.int32(0)))
-    return st[3], st[4]
+    (_, ents, card), chunks = _sweep(
+        di, qlo, qhi, p, visit,
+        (jnp.full((p.c_e,), _I32_MAX, jnp.int32),
+         jnp.full((p.c_e,), -1, jnp.int32), jnp.int32(0)))
+    return ents, card, chunks
 
 
 def route_level_card(di, qlo: jax.Array, qhi: jax.Array, p) -> jax.Array:
     """Estimate-only sweep: the () int32 in-range cardinality bound of
     ``route_level_sync`` without the entry scans — same traversal, same
-    ``frontier_cap`` contract, but no per-level entry-scan gathers, so
-    the planner's plan pass costs a fraction of a full
-    route (DESIGN.md §10)."""
-    F = p.frontier_cap
-    _require_frontier(F)
-    m = di.attrs.shape[1]
-    full = (1 << m) - 1
-    H = di.nbrs.shape[1]
+    ``frontier_cap`` contract, but no entry-scan gathers, so the
+    planner's plan pass costs a fraction of a full route (DESIGN.md
+    §10)."""
+    def visit(node, do_scan, card):
+        return card + jnp.sum(jnp.where(do_scan, di.count[node], 0))
 
-    def level(_lvl, st):
-        fnode, fD, card = st
-        node, do_scan, fnode, fD = _frontier_step(di, qlo, qhi, F, full,
-                                                  fnode, fD)
-        return fnode, fD, card + jnp.sum(jnp.where(do_scan,
-                                                   di.count[node], 0))
-
-    st = jax.lax.fori_loop(0, H, level, (*_frontier0(di, qlo, qhi, m, F),
-                                         jnp.int32(0)))
-    return st[2]
+    return _sweep(di, qlo, qhi, p, visit, jnp.int32(0))[0]
 
 
 def route_level_windows(di, qlo: jax.Array, qhi: jax.Array, p, *,
@@ -369,36 +415,25 @@ def route_level_windows(di, qlo: jax.Array, qhi: jax.Array, p, *,
     the collective caller derives it from static index counts (every
     window has count >= 1 and windows are DFS-disjoint), so the
     overflow clamp below is unreachable there."""
-    F = p.frontier_cap
-    _require_frontier(F)
-    m = di.attrs.shape[1]
-    full = (1 << m) - 1
-    H = di.nbrs.shape[1]
-
-    wstart0 = jnp.full((W,), _I32_MAX, jnp.int32)   # i32max pads sort last
-    wcount0 = jnp.zeros((W,), jnp.int32)
-
-    def level(_lvl, st):
-        fnode, fD, card, n_small, n_large, wstart, wcount, fill = st
-        node, do_scan, fnode, fD = _frontier_step(di, qlo, qhi, F, full,
-                                                  fnode, fD)
+    def visit(node, do_scan, acc):
+        card, n_small, n_large, wstart, wcount, fill = acc
         cnt = di.count[node]
-        card = card + jnp.sum(jnp.where(do_scan, cnt, 0))
         small = do_scan & (cnt > 0) & (cnt <= node_thr)
         large = do_scan & (cnt > node_thr)
         pos = fill + jnp.cumsum(small) - small          # exclusive
         slot = jnp.where(small, jnp.minimum(pos, W), W)  # W: drop (clamp)
         wstart = wstart.at[slot].set(di.start[node], mode="drop")
         wcount = wcount.at[slot].set(cnt, mode="drop")
-        return (fnode, fD, card,
+        return (card + jnp.sum(jnp.where(do_scan, cnt, 0)),
                 n_small + jnp.sum(small), n_large + jnp.sum(large),
                 wstart, wcount, jnp.minimum(fill + jnp.sum(small), W))
 
-    st = jax.lax.fori_loop(
-        0, H, level, (*_frontier0(di, qlo, qhi, m, F), jnp.int32(0),
-                      jnp.int32(0), jnp.int32(0), wstart0, wcount0,
-                      jnp.int32(0)))
-    _, _, card, n_small, n_large, wstart, wcount, _ = st
+    zero = jnp.int32(0)
+    card, n_small, n_large, wstart, wcount, _ = _sweep(
+        di, qlo, qhi, p, visit,
+        (zero, zero, zero,
+         jnp.full((W,), _I32_MAX, jnp.int32),   # i32max pads sort last
+         jnp.zeros((W,), jnp.int32), zero))[0]
     # antichain extents are disjoint -> starts unique among real windows;
     # stable ascending sort puts the i32max pads last
     o = jnp.argsort(wstart, stable=True)
@@ -555,7 +590,7 @@ def required_frontier_cap(di) -> int:
 
 
 def resolve_router(name: str) -> Callable:
-    """Router name -> route(di, qlo, qhi, p) -> (entries, card)
+    """Router name -> route(di, qlo, qhi, p) -> (entries, card, chunks)
     (the Phase-A contract)."""
     if name == "level":
         return route_level_sync

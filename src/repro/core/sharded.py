@@ -152,13 +152,15 @@ def _local_to_global(local_ids: jax.Array, shard: jax.Array,
 def _shard_search(di: DeviceIndex, shard_id: jax.Array, n_shards: int,
                   queries, qlo, qhi, p: SearchParams, scorer,
                   exact_scorer=None):
+    """One shard's vmapped graph search -> (global ids (B, k), dists
+    (B, k), hops (B,), router chunks per level (B, H))."""
     fn = functools.partial(_query_one, p=p, scorer=scorer,
                            exact_scorer=exact_scorer)
-    ids, dists, hops = jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(
+    ids, dists, hops, chunks = jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(
         queries, qlo, qhi)
     gids = _local_to_global(ids, shard_id, n_shards)
     dists = jnp.where(gids >= 0, dists, jnp.inf)
-    return gids, dists, hops
+    return gids, dists, hops, chunks
 
 
 def _merge_topk(gids, dists, k):
@@ -343,9 +345,9 @@ def make_sharded_search_fn(params: SearchParams, mesh: Mesh, *,
         B = queries.shape[0]
 
         def graph_pass(lo, hi):
-            gids, dists, _ = _shard_search(di, shard_id, n_shards, queries,
-                                           lo, hi, p, scorer,
-                                           exact_scorer=exact)
+            gids, dists, _, _ = _shard_search(di, shard_id, n_shards,
+                                              queries, lo, hi, p, scorer,
+                                              exact_scorer=exact)
             return gids, dists
 
         if strategy == "graph":
@@ -480,7 +482,7 @@ def search_sharded_emulated(skhi: ShardedKHI, queries, qlo, qhi,
         def per_shard(di, off):
             return _shard_search(di, off, n_shards, queries, qlo, qhi,
                                  params, scorer, exact_scorer=exact)
-        gids, dists, hops = jax.vmap(per_shard)(skhi.di, skhi.offsets)
+        gids, dists, hops, _ = jax.vmap(per_shard)(skhi.di, skhi.offsets)
         mi, md = _merge_topk(gids, dists, params.k)
         return mi, md, hops
 

@@ -37,6 +37,24 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def router_sweep_share(svc, snap) -> float:
+    """``router_chunks`` over what its graph runs' level routers would
+    have run had every level swept the whole frontier:
+    graph_runs · H · ceil(frontier_cap / C), C the chunk width
+    (``core/router.py``). Near 0 when the live frontiers are narrow, 1
+    when every level of every run fills ``frontier_cap``; 0 where no
+    level router ran (``mesh=``, ``--router dfs``)."""
+    from repro.core import router
+
+    di = getattr(svc.index, "di", svc.index)
+    F = svc.params.frontier_cap
+    if not (F and snap["graph_runs"]):
+        return 0.0
+    whole = snap["graph_runs"] * di.nbrs.shape[-2] * -(
+        -F // min(router._CHUNK, F))
+    return snap["router_chunks"] / whole
+
+
 def serve_khi(args):
     from repro.core import KHIConfig, KHIIndex, SearchParams
     from repro.core.sharded import build_sharded
@@ -98,6 +116,10 @@ def serve_khi(args):
           f"graph_lane_steps={snap['graph_lane_steps']} (hops per lane "
           f"{snap['graph_hops'] / lanes if lanes else 0:.1f}, hop-loop "
           f"occupancy {snap['graph_hops'] / max(snap['graph_lane_steps'], 1):.2f})")
+    print(f"[serve] level router, warm-up included: graph_runs="
+          f"{snap['graph_runs']} router_chunks={snap['router_chunks']} "
+          f"(share of a whole-frontier sweep "
+          f"{router_sweep_share(svc, snap):.4f})")
     print(f"[serve] backend={args.backend} E={args.expand_width} "
           f"router={args.router} strategy={args.strategy} "
           f"batches={snap['batches']} scan_lanes={snap['scan_lanes']} "

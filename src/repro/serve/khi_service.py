@@ -88,13 +88,17 @@ that holds ``khi.cache`` (result-cache hashing, lookups and puts), the
 Planner's ``khi.plan``, one ``khi.dispatch`` / ``khi.fetch`` pair per
 device program run (arg ``program``) and ``khi.merge`` (host merges);
 the graph program names its two phases with the ``khi_router`` and
-``khi_hop_loop`` scopes. ``stats`` counts the hop loop's work, beside
-``scan_lanes``: ``graph_lanes`` (real lanes that ran the graph program),
-``graph_hops`` (their hops) and ``graph_lane_steps`` (per graph program
-run, the lanes it ran, pads included, times its largest hop count: the
-lane-iterations the lockstep loop paid for). Under ``mesh=`` hops never
-reach the host, so these three do not move there. ``last_routes`` /
-``last_hops`` hold the last batch's per-lane dispatch record.
+``khi_hop_loop`` scopes. ``stats`` counts the graph program's work,
+beside ``scan_lanes``: ``graph_lanes`` (real lanes that ran the graph
+program), ``graph_hops`` (their hops), ``graph_lane_steps`` (per graph
+program run, the lanes it ran, pads included, times its largest hop
+count: the lane-iterations the lockstep loop paid for), ``graph_runs``
+(graph program runs) and ``router_chunks`` (per run, the chunk
+iterations the level router's lockstep loops ran: per tree level, the
+largest ceil(live frontier / chunk) over the run's lanes, summed over
+levels; ``core/router.py``). Under ``mesh=`` these never reach the
+host, so they do not move there. ``last_routes`` / ``last_hops`` hold
+the last batch's per-lane dispatch record.
 """
 
 from __future__ import annotations
@@ -115,6 +119,7 @@ from ..core.delta import StreamingState
 from ..core.engine import (SCAN_BACKENDS, DeviceIndex, Planner, SearchParams,
                            _fetch, _merge_dedup, _query_one,
                            device_put_index, resolve_scorer_pair,
+                           router_chunks,
                            validate_search_params, with_quant_replica)
 from ..core.khi import KHIConfig, KHIIndex
 from ..core.predicate import canonical_key, compile_expr, validate_expr
@@ -217,6 +222,7 @@ class KHIService:
             "requests": 0, "cache_hits": 0, "batches": 0, "pad_lanes": 0,
             "traced_buckets": set(), "epoch_swaps": 0, "scan_lanes": 0,
             "graph_lanes": 0, "graph_hops": 0, "graph_lane_steps": 0,
+            "graph_runs": 0, "router_chunks": 0,
             "inserts": 0, "deletes": 0, "compactions": 0,
             "ingest_seconds": 0.0, "compact_seconds": 0.0,
             "tier_lanes": collections.Counter(),
@@ -374,8 +380,9 @@ class KHIService:
 
     def _build_search_fn(self, tier: int = 0):
         # Each closure takes one bucket-padded host batch and returns host
-        # (ids, dists, hops, plan): hops (bucket,) is None where the host
-        # never sees it (mesh=), plan the Planner's Plan or None.
+        # (ids, dists, hops, plan, router chunks): hops (bucket,) is None
+        # where the host never sees it (mesh=, where the chunks read 0),
+        # plan the Planner's Plan or None.
         # Every branch reads ``self.index`` at CALL time (not build time):
         # a streaming delete installs a functionally-updated pytree of
         # identical shapes, which the jitted programs must pick up without
@@ -423,7 +430,7 @@ class KHIService:
                     # compiled-predicate observability (§15): fold this
                     # box's per-lane dispatch into predicate_lanes
                     Planner._count_lanes(plan, self._pred_lanes, q.shape[0])
-                return ids, dists, hops, plan
+                return ids, dists, hops, plan, plan.router_chunks
 
             return run
         # strategy="graph": every lane runs the graph program, which
@@ -434,8 +441,9 @@ class KHIService:
             def single(di: DeviceIndex, q, qlo, qhi):
                 fn = functools.partial(_query_one, p=p, scorer=scorer,
                                        exact_scorer=exact)
-                return jax.vmap(
+                ids, dists, hops, chunks = jax.vmap(
                     lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
+                return ids, dists, hops, router_chunks(chunks)
 
             self._hlo_fns[tier] = self._jit_hlo("graph", single)
             return self._device_run("graph", single)
@@ -447,22 +455,26 @@ class KHIService:
             def per_shard(di, off):
                 return _shard_search(di, off, n_shards, q, qlo, qhi,
                                      p, scorer, exact_scorer=exact)
-            gids, dists, hops = jax.vmap(per_shard)(skhi.di, skhi.offsets)
-            return _merge_topk(gids, dists, p.k) + (jnp.max(hops, axis=0),)
+            gids, dists, hops, chunks = jax.vmap(per_shard)(skhi.di,
+                                                            skhi.offsets)
+            return _merge_topk(gids, dists, p.k) + (
+                jnp.max(hops, axis=0), router_chunks(chunks))
 
         self._hlo_fns[tier] = self._jit_hlo("graph", fanout)
         return self._device_run("graph", fanout)
 
     def _device_run(self, program: str, fn):
         """Host closure over a whole-batch program ``fn(index, q, lo, hi)``
-        that returns (ids, dists) or (ids, dists, hops), under the
-        ``khi.dispatch`` / ``khi.fetch`` profiler spans."""
+        that returns (ids, dists) or (ids, dists, hops, router chunks),
+        under the ``khi.dispatch`` / ``khi.fetch`` profiler spans."""
         def run(q, lo, hi):
             with TraceAnnotation("khi.dispatch", program=program):
                 out = fn(self.index, jnp.asarray(q), jnp.asarray(lo),
                          jnp.asarray(hi))
-            ids, dists, *hops = _fetch(program, out)
-            return ids, dists, (hops[0] if hops else None), None
+            ids, dists, *graph = _fetch(program, out)
+            if not graph:
+                return ids, dists, None, None, 0
+            return ids, dists, graph[0], None, int(graph[1])
         return run
 
     def _jit_hlo(self, name: str, fn):
@@ -541,7 +553,7 @@ class KHIService:
             his = np.concatenate(
                 [his, np.full((pad, self.m), -np.inf, np.float32)])
         search = self._search if tier == 0 else self._get_search_fn(tier)
-        ids, dists, hops, plan = search(qs, los, his)
+        ids, dists, hops, plan, chunks = search(qs, los, his)
         if self._stream is not None:
             # windowed merge (DESIGN.md §11): fold the per-shard delta
             # scans into the epoch results on the bucket-padded batch (the
@@ -563,7 +575,7 @@ class KHIService:
         routes = self._routes(plan, bucket, hops is None)[:b]
         if hops is not None:
             hops = hops[:b]
-            self._count_graph(plan, hops, routes, bucket)
+            self._count_graph(plan, hops, routes, bucket, chunks)
         return ids[:b], dists[:b], hops, routes
 
     @staticmethod
@@ -581,17 +593,21 @@ class KHIService:
         return np.where(plan.use_scan, "scan", "graph")
 
     def _count_graph(self, plan, hops: np.ndarray, routes: np.ndarray,
-                     bucket: int) -> None:
-        """Hop-loop counters of one device batch, ``hops`` and ``routes``
-        of its real lanes: graph_lanes, graph_hops (scan and window lanes
-        carry 0), and graph_lane_steps — for each graph program run, the
-        lanes it ran (pads included) times its largest hop count."""
+                     bucket: int, chunks: int) -> None:
+        """Graph-program counters of one device batch, ``hops`` and
+        ``routes`` of its real lanes: graph_lanes, graph_hops (scan and
+        window lanes carry 0), graph_lane_steps — for each graph program
+        run, the lanes it ran (pads included) times its largest hop
+        count — graph_runs, and router_chunks, the chunk iterations its
+        runs' level routers ran."""
         self.stats["graph_lanes"] += int(
             np.isin(routes, ("graph", "mixed")).sum())
         self.stats["graph_hops"] += int(hops.sum())
         runs = plan.graph_runs if plan is not None else [
             (bucket, int(hops.max(initial=0)))]
         self.stats["graph_lane_steps"] += sum(n * h for n, h in runs)
+        self.stats["graph_runs"] += len(runs)
+        self.stats["router_chunks"] += chunks
 
     # -------------------------------------------------------------- serving
     def _answer(self, queries: np.ndarray, lo: np.ndarray,

@@ -12,6 +12,8 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.core import engine as eng
+from repro.core import query_ref as qr
+from repro.core import router as rt
 from repro.serve import KHIService, ServeConfig
 
 BASE = dict(k=10, ef=32, c_n=16)
@@ -126,6 +128,39 @@ def test_counters_match_planner_hops_and_plan(tiny_index, batch, strategy):
     assert t["graph_hops"] - s["graph_hops"] == hops3.sum()
 
 
+@pytest.mark.parametrize("strategy", ["auto", "graph"])
+def test_router_chunks_counter(tiny_index, batch, strategy):
+    """router_chunks sums the chunk count each graph program run returns:
+    per level, the largest ceil(live / C) over the run's lanes, pads
+    included. With C >= frontier_cap (the tiny index's frontier is
+    narrower than one chunk) a level costs one chunk while any lane's
+    frontier is live there, and none once every lane's is empty, so a
+    run costs its deepest lane's level count, at most H. Cache hits add
+    nothing, nor do scan lanes: a lone scan lane's bucket pads (empty
+    boxes, card 0) take the graph program, whose root level alone is
+    live, one chunk."""
+    Q, lo, hi, thresh = batch
+    svc = _service(tiny_index, thresh, strategy)
+    assert svc.params.frontier_cap <= rt._CHUNK
+    _, _, _, plan = eng.Planner(tiny_index, svc.params).search(Q, lo, hi)
+    graph = np.nonzero(~plan.use_scan)[0]
+    levels = max(len(qr.frontier_widths(tiny_index, qr.Predicate(lo[i],
+                                                                 hi[i])))
+                 for i in graph)
+    H = eng.device_put_index(tiny_index).nbrs.shape[1]
+    assert plan.router_chunks == levels <= H * len(plan.graph_runs)
+    svc.search(Q, lo, hi)
+    s = svc.snapshot()["router_chunks"]
+    assert s == plan.router_chunks
+    svc.search(Q, lo, hi)                          # every lane a hit
+    assert svc.snapshot()["router_chunks"] == s
+    if strategy == "auto":
+        scan = np.nonzero(plan.use_scan)[0][:1]    # a scan-only batch
+        svc.search(Q[scan] + np.float32(1e-3), lo[scan], hi[scan])
+        assert list(svc.last_routes) == ["scan"]
+        assert svc.snapshot()["router_chunks"] == s + 1
+
+
 def test_answers_identical_with_profiler_on(tiny_index, batch, tmp_path):
     Q, lo, hi, thresh = batch
     off = _service(tiny_index, thresh, cache_size=0).search(Q, lo, hi)
@@ -139,4 +174,5 @@ def test_answers_identical_with_profiler_on(tiny_index, batch, tmp_path):
 def test_snapshot_reports_counters_not_device_qps(tiny_index, batch):
     snap = _service(tiny_index, batch[3]).snapshot()
     assert not {"device_qps", "device_seconds", "device_queries"} & set(snap)
-    assert {"graph_lanes", "graph_hops", "graph_lane_steps"} <= set(snap)
+    assert {"graph_lanes", "graph_hops", "graph_lane_steps",
+            "router_chunks"} <= set(snap)
