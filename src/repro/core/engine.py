@@ -70,7 +70,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -739,10 +739,10 @@ def _lex_topk(ids: jax.Array, dists: jax.Array,
 def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
                p: SearchParams, scorer: Scorer,
                exact_scorer: Optional[Scorer] = None
-               ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+               ) -> tuple[jax.Array, jax.Array, jax.Array, "LaneCounts"]:
     """One query's two-phase search -> (ids (k,), dists (k,), hops (),
-    router chunks per tree level (H,); ``router_chunks`` reduces the
-    last over a vmapped batch)."""
+    LaneCounts; ``graph_counters`` reduces the last over a vmapped
+    batch)."""
     n = di.n
     H, M = di.nbrs.shape[1], di.nbrs.shape[2]
     HM = H * M
@@ -754,7 +754,7 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     # The two named scopes are op_name metadata only: they name the
     # phases in a profile of every graph program (DESIGN.md §3)
     with jax.named_scope("khi_router"):
-        entries, _, chunks = resolve_router(p.router)(di, qlo, qhi, p)
+        entries, _, sweep = resolve_router(p.router)(di, qlo, qhi, p)
     e_valid = entries >= 0
     e_dist = scorer.score(di, q, qlo, qhi, entries)
 
@@ -768,11 +768,11 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     seen0 = jnp.full((n,), -1, jnp.int32)
 
     def cond(st):
-        pool, visited, seen, hops = st
+        pool, hops = st[0], st[3]
         return beam.pool_frontier_alive(pool, p.ef) & (hops < p.hops())
 
     def body(st):
-        pool, visited, seen, hops = st
+        pool, visited, seen, hops, n_fresh, n_inbox = st
         # -------- wide frontier: top-E unexpanded, closest first
         u_slots, us, uvalid = beam.pool_top_unexpanded(pool, p.ef, E)
         pool = beam.pool_mark_expanded_many(pool, u_slots, uvalid)
@@ -822,13 +822,16 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
 
         # -------- pool merge (Alg. 3 lines 10-13)
         pool = beam.pool_merge_tail(pool, p.ef, buf, bd, bvalid)
-        return pool, visited, seen, hops + 1
+        return (pool, visited, seen, hops + 1, n_fresh + jnp.sum(fresh),
+                n_inbox + jnp.sum(append))
 
+    zero = jnp.int32(0)
     with jax.named_scope("khi_hop_loop"):
-        pool, visited, seen, hops = jax.lax.while_loop(
-            cond, body, (pool0, visited, seen0, jnp.int32(0)))
+        pool, _, _, hops, n_fresh, n_inbox = jax.lax.while_loop(
+            cond, body, (pool0, visited, seen0, zero, zero, zero))
+    counts = LaneCounts(*sweep, n_fresh, n_inbox)
     if exact_scorer is None:
-        return pool.ids[: p.k], pool.dists[: p.k], hops, chunks
+        return pool.ids[: p.k], pool.dists[: p.k], hops, counts
     # quantized rerank tail (DESIGN.md §12): the loop above ranked the
     # pool on compressed-replica distances, so the quantized order near
     # the k boundary may invert vs f32. Rescore the top
@@ -839,16 +842,45 @@ def _query_one(di: DeviceIndex, q: jax.Array, qlo: jax.Array, qhi: jax.Array,
     cand = pool.ids[:rr]
     exact_d = exact_scorer.score(di, q, qlo, qhi, cand)
     ids_k, dists_k = _lex_topk(cand, exact_d, p.k)
-    return ids_k, dists_k, hops, chunks
+    return ids_k, dists_k, hops, counts
 
 
-def router_chunks(chunks: jax.Array) -> jax.Array:
-    """(..., H) per-lane router chunks per level -> () int32: the chunk
-    iterations the level router's loops ran for the whole vmapped
-    program. A level's lockstep loop runs to its largest count over the
-    lanes (and shards), so this is that largest count, summed over the
-    levels."""
-    return jnp.sum(jnp.max(chunks.reshape(-1, chunks.shape[-1]), axis=0))
+class LaneCounts(NamedTuple):
+    """One lane's work in the graph program: the level router's
+    ``SweepCounts`` per tree level (H,) each, and over the hop loop ()
+    the neighbours first seen and not yet visited (``fresh``) and those
+    of them in the box (``inbox``)."""
+    chunks: jax.Array
+    live: jax.Array
+    scan_steps: jax.Array
+    fresh: jax.Array
+    inbox: jax.Array
+
+
+# the stats a graph program run adds to (KHIService.stats), in the order
+# of graph_counters' vector
+GRAPH_COUNTERS = ("router_chunks", "router_live", "router_scan_steps",
+                  "graph_fresh", "graph_inbox")
+
+
+def level_lockstep(per_level: jax.Array) -> jax.Array:
+    """(..., H) per-lane counts per tree level -> () int32: per level the
+    largest over the lanes (and shards), summed over the levels. For
+    ``chunks`` that is what the level router's lockstep chunk loops ran
+    for the whole vmapped program; for the entry-scan steps, nested
+    deeper, it is a lower bound on what their loops ran."""
+    return jnp.sum(jnp.max(per_level.reshape(-1, per_level.shape[-1]),
+                           axis=0))
+
+
+def graph_counters(c: LaneCounts) -> jax.Array:
+    """A vmapped batch's LaneCounts -> (5,) int32 in ``GRAPH_COUNTERS``
+    order: the router's three reduced by ``level_lockstep``, the hop
+    loop's two summed over every lane (a pad lane's empty box reaches
+    no entry, so its loop runs no hop and adds 0)."""
+    return jnp.stack([level_lockstep(c.chunks), level_lockstep(c.live),
+                      level_lockstep(c.scan_steps), jnp.sum(c.fresh),
+                      jnp.sum(c.inbox)]).astype(jnp.int32)
 
 
 def make_search_fn(p: SearchParams, *, dist_fn=None, donate: bool = False,
@@ -1109,9 +1141,11 @@ class Plan:
     ``graph_runs`` is filled by ``Planner.search``: one ``(lanes, hops)``
     pair per graph program run that served the batch — the lanes it ran,
     pow2 pads included, and its largest hop count. Their product is the
-    lane-iterations the lockstep hop loop paid for. ``router_chunks``
-    sums, over the same runs, the chunk iterations the level router's
-    loops ran (``router_chunks()``)."""
+    lane-iterations the lockstep hop loop paid for. ``counters`` sums,
+    over the same runs, what each returned of ``GRAPH_COUNTERS``
+    (``graph_counters``): the level router's chunk iterations, live
+    frontier and entry-scan steps, and the hop loop's fresh and in-box
+    neighbours."""
 
     card: np.ndarray       # (B,) int64/int32
     use_scan: np.ndarray   # (B,) bool
@@ -1121,7 +1155,8 @@ class Plan:
     n_windows: Optional[np.ndarray] = None    # (B,) int64, hybrid only
     small_nodes: Optional[list] = None        # per-shard (B, P) bool
     graph_runs: list = dataclasses.field(default_factory=list)
-    router_chunks: int = 0
+    counters: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
 
 @dataclasses.dataclass
@@ -1402,9 +1437,9 @@ class Planner:
             def graph(di, q, qlo, qhi):
                 fn = functools.partial(_query_one, p=p, scorer=scorer,
                                        exact_scorer=exact)
-                ids, dists, hops, chunks = jax.vmap(
+                ids, dists, hops, counts = jax.vmap(
                     lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
-                return ids, dists, hops, router_chunks(chunks)
+                return ids, dists, hops, graph_counters(counts)
             self._programs["graph"] = (graph, lambda: (self.index,))
             return lambda q, qlo, qhi: graph(self.index, q, qlo, qhi)
 
@@ -1416,10 +1451,10 @@ class Planner:
             def per_shard(di, off):
                 return _shard_search(di, off, S, q, qlo, qhi, p, scorer,
                                      exact_scorer=exact)
-            gids, dists, hops, chunks = jax.vmap(per_shard)(skhi.di,
+            gids, dists, hops, counts = jax.vmap(per_shard)(skhi.di,
                                                             skhi.offsets)
             mi, md = _merge_topk(gids, dists, p.k)
-            return mi, md, jnp.max(hops, axis=0), router_chunks(chunks)
+            return mi, md, jnp.max(hops, axis=0), graph_counters(counts)
 
         self._programs["graph"] = (graph_sharded, lambda: (self.index,))
         return lambda q, qlo, qhi: graph_sharded(self.index, q, qlo, qhi)
@@ -1602,15 +1637,15 @@ class Planner:
     def _run_graph(self, qs, lo, hi, plan: Plan, pow2: bool = False):
         """One graph program run (``pow2``: pad a split sub-batch first);
         records ``(lanes, largest hop count)`` in ``plan.graph_runs`` and
-        adds its router chunks to ``plan.router_chunks``."""
+        adds its ``GRAPH_COUNTERS`` to ``plan.counters``."""
         with TraceAnnotation("khi.dispatch", program="graph"):
             if pow2:
                 qs, lo, hi = self._pad_pow2(qs, lo, hi)
             out = self._graph_fn(jnp.asarray(qs), jnp.asarray(lo),
                                  jnp.asarray(hi))
-        ids, dists, hops, chunks = _fetch("graph", out)
+        ids, dists, hops, counts = _fetch("graph", out)
         plan.graph_runs.append((qs.shape[0], int(hops.max(initial=0))))
-        plan.router_chunks += int(chunks)
+        plan.counters.update(dict(zip(GRAPH_COUNTERS, counts.tolist())))
         return ids, dists, hops
 
     def _run_scan(self, qs, lo, hi, pow2: bool = False):

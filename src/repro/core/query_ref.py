@@ -135,9 +135,13 @@ def range_filter(index: KHIIndex, pred: Predicate, c_e: int,
             # Deviation (DESIGN.md §6): the pseudocode skips leaves with
             # |D| < m, which starves entry selection when leaf cells are
             # wider than the query window (small corpora / per-shard
-            # indexes). Leaves hold <= c_l objects, so an exact predicate
-            # scan is O(c_l) and restores the guarantee that entries exist
-            # whenever O_B intersects an explored branch.
+            # indexes). A leaf reached here holds <= c_l objects, so an
+            # exact predicate scan is O(c_l) and restores the guarantee
+            # that entries exist whenever O_B intersects an explored
+            # branch. Leaves may hold more only where ties left every dim
+            # blacklisted (tree.build_tree; laion-768 has 168, up to 141
+            # rows): their D is full, so the covered branch above scans
+            # them.
             e = scan_entry(p)
             if e is not None:
                 entries.append(e)

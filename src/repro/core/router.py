@@ -5,15 +5,15 @@
 Routing finds up to ``c_e`` entry points in O_B by walking the attribute
 partition tree. Two device implementations share one contract
 (``route(di, qlo, qhi, p) -> ((c_e,) int32 entry ids, -1 padded, in DFS
-order; () int32 in-range cardinality bound; (H,) int32 chunks each tree
-level swept)``) and return **identical entry vectors** (pinned by
+order; () int32 in-range cardinality bound; SweepCounts, the sweep's
+work per tree level)``) and return **identical entry vectors** (pinned by
 tests/test_router.py):
 
   * ``route_dfs`` — the legacy per-query stack DFS ``lax.while_loop``
-    (one node pop per iteration; it sweeps no chunks). Inside the
-    vmapped batch every lane pays the slowest lane's pop count: the
-    while_loop is lockstep, so a single deep query serializes the whole
-    batch.
+    (one node pop per iteration; it sweeps no chunks: its counts are
+    0). Inside the vmapped batch every lane pays the slowest lane's pop
+    count: the while_loop is lockstep, so a single deep query
+    serializes the whole batch.
   * ``route_level_sync`` — the production router: a ``lax.scan`` over
     tree **levels** (height is O(log n), Lemma 1) with a per-query
     frontier of (node, D-bitmask) pairs, ``frontier_cap`` slots wide.
@@ -73,7 +73,7 @@ all levels). ``route_level_card`` is the estimate-only form and
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Callable
+from typing import TYPE_CHECKING, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -82,9 +82,9 @@ import numpy as np
 if TYPE_CHECKING:  # pragma: no cover
     from .engine import DeviceIndex, SearchParams
 
-__all__ = ["ROUTERS", "resolve_router", "route_dfs", "route_level_sync",
-           "route_level_card", "route_level_windows", "HostCardEstimator",
-           "deleted_per_node", "required_frontier_cap"]
+__all__ = ["ROUTERS", "SweepCounts", "resolve_router", "route_dfs",
+           "route_level_sync", "route_level_card", "route_level_windows",
+           "HostCardEstimator", "deleted_per_node", "required_frontier_cap"]
 
 ROUTERS = ("level", "dfs")
 
@@ -103,6 +103,16 @@ _SCAN_STEP = 128
 _CHUNK = 16384
 
 
+class SweepCounts(NamedTuple):
+    """One lane's level-router work, each (H,) int32 per tree level
+    (``engine.graph_counters`` reduces a batch's): the chunk iterations
+    the level ran, its live frontier width, and the ``first_in_box``
+    iterations its entry scans ran."""
+    chunks: jax.Array
+    live: jax.Array
+    scan_steps: jax.Array
+
+
 def _root_D0(di, qlo, qhi, m: int) -> jax.Array:
     """D seeded with dims the root rectangle already covers."""
     root_cov = ((di.lo[di.root] >= qlo) & (di.hi[di.root] <= qhi))
@@ -115,10 +125,11 @@ def _root_D0(di, qlo, qhi, m: int) -> jax.Array:
 
 def route_dfs(di, qlo: jax.Array, qhi: jax.Array, p):
     """Returns (entry-point object ids (c_e,), -1 padded, DFS order;
-    () int32 sum of scanned-node counts; (H,) int32 zeros: no chunks).
-    The DFS early-stops after c_e entries, so its count sum covers only
-    the visited antichain prefix — NOT an |O_B| bound (module
-    docstring); the planner requires the level router for that."""
+    () int32 sum of scanned-node counts; SweepCounts of zeros: no level
+    sweep). The DFS early-stops after c_e entries, so its count sum
+    covers only the visited antichain prefix — NOT an |O_B| bound
+    (module docstring); the planner requires the level router for
+    that."""
     m = di.attrs.shape[1]
     full = (1 << m) - 1
     S = p.stack_cap
@@ -198,7 +209,8 @@ def route_dfs(di, qlo: jax.Array, qhi: jax.Array, p):
         return (stack_node, stack_D, sp, entries, n_e, card, steps + 1)
 
     state = jax.lax.while_loop(cond, body, state)
-    return state[3], state[5], jnp.zeros((di.nbrs.shape[1],), jnp.int32)
+    zero = jnp.zeros((di.nbrs.shape[1],), jnp.int32)
+    return state[3], state[5], SweepCounts(zero, zero, zero)
 
 
 # --------------------------------------------------------------------------
@@ -253,7 +265,8 @@ def _frontier_step(di, qlo, qhi, full: int, fnode, fD, alive):
             jnp.stack([vl, vr], axis=1).reshape(C2))
 
 
-def _sweep(di, qlo, qhi, p, visit: Callable, acc0):
+def _sweep(di, qlo, qhi, p, visit: Callable, acc0,
+           tally: Callable = lambda acc: jnp.int32(0)):
     """The level sweep shared by the three level routers (module
     docstring): a ``lax.scan`` over the H tree levels, each a
     ``while_loop`` over the live prefix ``[0, live)`` of the frontier in
@@ -264,9 +277,10 @@ def _sweep(di, qlo, qhi, p, visit: Callable, acc0):
     cumsum gives, so the next frontier, and its overflow clamp at
     ``frontier_cap``, are those of a whole-frontier sweep.
 
-    Returns (acc, (H,) int32 chunks each level ran for this lane); under
-    ``vmap`` a level's loop runs to the batch's largest count, which
-    ``engine.router_chunks`` reads back."""
+    Returns (acc, and per level (H,) int32: the chunks it ran for this
+    lane, its live frontier width, and ``tally(acc)`` at its end); under
+    ``vmap`` a level's loop runs to the batch's largest chunk count,
+    which ``engine.graph_counters`` reads back."""
     F = p.frontier_cap
     _require_frontier(F)
     m = di.attrs.shape[1]
@@ -302,21 +316,22 @@ def _sweep(di, qlo, qhi, p, visit: Callable, acc0):
             more, chunk, (jnp.int32(0), gnode, gD, jnp.int32(0), acc))
         # the next level reads gnode; this level's buffers take its
         # children in turn
-        return (gnode, gD, jnp.minimum(nxt, F), fnode, fD, acc), nc
+        return ((gnode, gD, jnp.minimum(nxt, F), fnode, fD, acc),
+                (nc, live, tally(acc)))
 
     fnode = jnp.zeros((Fp,), jnp.int32).at[0].set(di.root)
     fD = jnp.zeros((Fp,), jnp.int32).at[0].set(_root_D0(di, qlo, qhi, m))
-    st, chunks = jax.lax.scan(
+    st, per_level = jax.lax.scan(
         level, (fnode, fD, jnp.int32(1), jnp.zeros_like(fnode),
                 jnp.zeros_like(fD), acc0), None, length=H)
-    return st[5], chunks
+    return st[5], per_level
 
 
 def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
     """Returns (entry-point object ids (c_e,), -1 padded, DFS order;
     () int32 in-range cardinality bound — the full-antichain count sum,
-    module docstring; (H,) int32 chunks per level). The DFS-rank key
-    makes the two routers' entry vectors agree."""
+    module docstring; SweepCounts per level). The DFS-rank key makes the
+    two routers' entry vectors agree."""
     _require_frontier(p.frontier_cap)
     n = di.order.shape[0]
     SB = p.scan_budget
@@ -325,9 +340,10 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
     lane = jnp.arange(W)
 
     def first_in_box(sj, cj):
-        """(T,) first object of each node, in DFS order, that lies in the
-        box (-1: none among its first min(count, scan_budget)) — read W
-        objects at a time until every node has its hit or runs out."""
+        """((T,) first object of each node, in DFS order, that lies in
+        the box (-1: none among its first min(count, scan_budget)); ()
+        the steps it took) — read W objects at a time until every node
+        has its hit or runs out."""
         lim = jnp.minimum(cj, SB)
 
         def more(c):
@@ -345,11 +361,12 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
                 win, jnp.argmax(ok, axis=1)[:, None], axis=1)[:, 0]
             return off + W, jnp.where((e < 0) & ok.any(axis=1), hit, e)
 
-        return jax.lax.while_loop(
-            more, step, (jnp.int32(0), jnp.full(sj.shape, -1, jnp.int32)))[1]
+        off, e = jax.lax.while_loop(
+            more, step, (jnp.int32(0), jnp.full(sj.shape, -1, jnp.int32)))
+        return e, off // W
 
     def visit(node, do_scan, acc):
-        keys, ents, card = acc
+        keys, ents, card, steps = acc
         s = di.start[node]
         cnt = di.count[node]
         card = card + jnp.sum(jnp.where(do_scan, cnt, 0))
@@ -363,29 +380,37 @@ def route_level_sync(di, qlo: jax.Array, qhi: jax.Array, p):
         # scanned node could still enter the running c_e smallest keys:
         # the same entries as scanning the whole sweep at once
         def pending(c):
-            cand, keys, _ = c
+            cand, keys = c[:2]
             return jnp.any(cand < keys[-1])
 
         def scan_step(c):
-            cand, keys, ents = c
+            cand, keys, ents, steps = c
             neg, j = jax.lax.top_k(-cand, T)
             live = -neg < keys[-1]
-            e = first_in_box(s[j], jnp.where(live, cnt[j], 0))
+            e, took = first_in_box(s[j], jnp.where(live, cnt[j], 0))
             key = jnp.where(e >= 0, -neg, _I32_MAX)
             allk = jnp.concatenate([keys, key])
             alle = jnp.concatenate([ents, e])
             srt = jnp.argsort(allk, stable=True)[: p.c_e]
-            return cand.at[j].set(_I32_MAX), allk[srt], alle[srt]
+            return (cand.at[j].set(_I32_MAX), allk[srt], alle[srt],
+                    steps + took)
 
-        _, keys, ents = jax.lax.while_loop(pending, scan_step,
-                                           (cand, keys, ents))
-        return keys, ents, card
+        # op_name metadata only: splits the router's entry scans from
+        # its sweep in a profile (DESIGN.md §3)
+        with jax.named_scope("khi_entry_scan"):
+            _, keys, ents, steps = jax.lax.while_loop(
+                pending, scan_step, (cand, keys, ents, steps))
+        return keys, ents, card, steps
 
-    (_, ents, card), chunks = _sweep(
+    zero = jnp.int32(0)
+    (_, ents, card, _), (chunks, live, steps) = _sweep(
         di, qlo, qhi, p, visit,
         (jnp.full((p.c_e,), _I32_MAX, jnp.int32),
-         jnp.full((p.c_e,), -1, jnp.int32), jnp.int32(0)))
-    return ents, card, chunks
+         jnp.full((p.c_e,), -1, jnp.int32), zero, zero),
+        tally=lambda acc: acc[3])
+    # the tally is the running total at each level's end
+    return ents, card, SweepCounts(chunks, live,
+                                   jnp.diff(steps, prepend=zero))
 
 
 def route_level_card(di, qlo: jax.Array, qhi: jax.Array, p) -> jax.Array:
@@ -508,6 +533,15 @@ class HostCardEstimator:
         the nodes' ``[start, start + count)`` DFS ranges are disjoint —
         the hybrid planner's per-node dispatch set (DESIGN.md §12);
         ``cards`` is its count-weighted row sum."""
+        stop, reached = self.reach(qlo, qhi)
+        return stop & reached
+
+    def reach(self, qlo: np.ndarray, qhi: np.ndarray
+              ) -> tuple[np.ndarray, np.ndarray]:
+        """(B, m) boxes -> ((B, P) stop, (B, P) reached): the nodes the
+        sweep stops at if it gets there (covered or leaf), and the nodes
+        it gets to — on each tree level (``levels``), the level's live
+        frontier with an unclamped ``frontier_cap``."""
         B = qlo.shape[0]
         P = self.parent.shape[0]
         pa = np.maximum(self.parent, 0)
@@ -530,7 +564,7 @@ class HostCardEstimator:
             pl = self.parent[nl]
             reached[:, nl] = (reached[:, pl] & ~stop[:, pl]
                               & edge_ok[:, nl])
-        return stop & reached
+        return stop, reached
 
     def cards(self, qlo: np.ndarray, qhi: np.ndarray) -> np.ndarray:
         return self.antichain(qlo, qhi) @ self.count
@@ -590,8 +624,8 @@ def required_frontier_cap(di) -> int:
 
 
 def resolve_router(name: str) -> Callable:
-    """Router name -> route(di, qlo, qhi, p) -> (entries, card, chunks)
-    (the Phase-A contract)."""
+    """Router name -> route(di, qlo, qhi, p) -> (entries, card,
+    SweepCounts) (the Phase-A contract)."""
     if name == "level":
         return route_level_sync
     if name == "dfs":

@@ -153,14 +153,14 @@ def _shard_search(di: DeviceIndex, shard_id: jax.Array, n_shards: int,
                   queries, qlo, qhi, p: SearchParams, scorer,
                   exact_scorer=None):
     """One shard's vmapped graph search -> (global ids (B, k), dists
-    (B, k), hops (B,), router chunks per level (B, H))."""
+    (B, k), hops (B,), engine.LaneCounts of the B lanes)."""
     fn = functools.partial(_query_one, p=p, scorer=scorer,
                            exact_scorer=exact_scorer)
-    ids, dists, hops, chunks = jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(
+    ids, dists, hops, counts = jax.vmap(lambda q, lo, hi: fn(di, q, lo, hi))(
         queries, qlo, qhi)
     gids = _local_to_global(ids, shard_id, n_shards)
     dists = jnp.where(gids >= 0, dists, jnp.inf)
-    return gids, dists, hops, chunks
+    return gids, dists, hops, counts
 
 
 def _merge_topk(gids, dists, k):

@@ -55,6 +55,19 @@ def router_sweep_share(svc, snap) -> float:
     return snap["router_chunks"] / whole
 
 
+def chunk_occupancy(svc, snap) -> float:
+    """``router_live`` over the slots the level router's chunks swept,
+    ``router_chunks`` · C: the share of swept slots that held a live
+    frontier node; 0 where no level router ran."""
+    from repro.core import router
+
+    F = svc.params.frontier_cap
+    if not (F and snap["router_chunks"]):
+        return 0.0
+    return snap["router_live"] / (snap["router_chunks"]
+                                  * min(router._CHUNK, F))
+
+
 def serve_khi(args):
     from repro.core import KHIConfig, KHIIndex, SearchParams
     from repro.core.sharded import build_sharded
@@ -119,7 +132,14 @@ def serve_khi(args):
     print(f"[serve] level router, warm-up included: graph_runs="
           f"{snap['graph_runs']} router_chunks={snap['router_chunks']} "
           f"(share of a whole-frontier sweep "
-          f"{router_sweep_share(svc, snap):.4f})")
+          f"{router_sweep_share(svc, snap):.4f}) "
+          f"router_live={snap['router_live']} (chunk occupancy "
+          f"{chunk_occupancy(svc, snap):.4f}) "
+          f"router_scan_steps={snap['router_scan_steps']}")
+    print(f"[serve] hop-loop filter, warm-up included: graph_fresh="
+          f"{snap['graph_fresh']} graph_inbox={snap['graph_inbox']} "
+          f"(in-box share "
+          f"{snap['graph_inbox'] / max(snap['graph_fresh'], 1):.4f})")
     print(f"[serve] backend={args.backend} E={args.expand_width} "
           f"router={args.router} strategy={args.strategy} "
           f"batches={snap['batches']} scan_lanes={snap['scan_lanes']} "

@@ -93,12 +93,18 @@ beside ``scan_lanes``: ``graph_lanes`` (real lanes that ran the graph
 program), ``graph_hops`` (their hops), ``graph_lane_steps`` (per graph
 program run, the lanes it ran, pads included, times its largest hop
 count: the lane-iterations the lockstep loop paid for), ``graph_runs``
-(graph program runs) and ``router_chunks`` (per run, the chunk
-iterations the level router's lockstep loops ran: per tree level, the
-largest ceil(live frontier / chunk) over the run's lanes, summed over
-levels; ``core/router.py``). Under ``mesh=`` these never reach the
-host, so they do not move there. ``last_routes`` / ``last_hops`` hold
-the last batch's per-lane dispatch record.
+(graph program runs), and per run what ``engine.graph_counters``
+returns: ``router_chunks`` (the chunk iterations the level router's
+lockstep loops ran: per tree level, the largest ceil(live frontier /
+chunk) over the run's lanes, summed over levels; ``core/router.py``),
+``router_live`` (per level the largest live frontier, summed),
+``router_scan_steps`` (per level the most ``first_in_box`` steps a
+lane's entry scans took, summed), ``graph_fresh`` (over the hop loop,
+neighbours first seen and not yet visited) and ``graph_inbox`` (those
+of them in the box). The graph program's router scope holds a
+``khi_entry_scan`` scope around its entry scans. Under ``mesh=`` these
+never reach the host, so they do not move there. ``last_routes`` /
+``last_hops`` hold the last batch's per-lane dispatch record.
 """
 
 from __future__ import annotations
@@ -117,10 +123,10 @@ from jax.profiler import TraceAnnotation
 
 from ..core.delta import StreamingState
 from ..core.engine import (SCAN_BACKENDS, DeviceIndex, Planner, SearchParams,
-                           _fetch, _merge_dedup, _query_one,
-                           device_put_index, resolve_scorer_pair,
-                           router_chunks,
-                           validate_search_params, with_quant_replica)
+                           GRAPH_COUNTERS, _fetch, _merge_dedup,
+                           _query_one, device_put_index, graph_counters,
+                           resolve_scorer_pair, validate_search_params,
+                           with_quant_replica)
 from ..core.khi import KHIConfig, KHIIndex
 from ..core.predicate import canonical_key, compile_expr, validate_expr
 from ..core.sharded import (ShardedKHI, _merge_topk, _shard_search,
@@ -222,7 +228,7 @@ class KHIService:
             "requests": 0, "cache_hits": 0, "batches": 0, "pad_lanes": 0,
             "traced_buckets": set(), "epoch_swaps": 0, "scan_lanes": 0,
             "graph_lanes": 0, "graph_hops": 0, "graph_lane_steps": 0,
-            "graph_runs": 0, "router_chunks": 0,
+            "graph_runs": 0, **dict.fromkeys(GRAPH_COUNTERS, 0),
             "inserts": 0, "deletes": 0, "compactions": 0,
             "ingest_seconds": 0.0, "compact_seconds": 0.0,
             "tier_lanes": collections.Counter(),
@@ -380,9 +386,10 @@ class KHIService:
 
     def _build_search_fn(self, tier: int = 0):
         # Each closure takes one bucket-padded host batch and returns host
-        # (ids, dists, hops, plan, router chunks): hops (bucket,) is None
-        # where the host never sees it (mesh=, where the chunks read 0),
-        # plan the Planner's Plan or None.
+        # (ids, dists, hops, plan, counters): hops (bucket,) is None
+        # where the host never sees it (mesh=, where counters is empty),
+        # plan the Planner's Plan or None, counters the graph program's
+        # GRAPH_COUNTERS by name.
         # Every branch reads ``self.index`` at CALL time (not build time):
         # a streaming delete installs a functionally-updated pytree of
         # identical shapes, which the jitted programs must pick up without
@@ -430,7 +437,7 @@ class KHIService:
                     # compiled-predicate observability (§15): fold this
                     # box's per-lane dispatch into predicate_lanes
                     Planner._count_lanes(plan, self._pred_lanes, q.shape[0])
-                return ids, dists, hops, plan, plan.router_chunks
+                return ids, dists, hops, plan, plan.counters
 
             return run
         # strategy="graph": every lane runs the graph program, which
@@ -441,9 +448,9 @@ class KHIService:
             def single(di: DeviceIndex, q, qlo, qhi):
                 fn = functools.partial(_query_one, p=p, scorer=scorer,
                                        exact_scorer=exact)
-                ids, dists, hops, chunks = jax.vmap(
+                ids, dists, hops, counts = jax.vmap(
                     lambda qq, lo, hi: fn(di, qq, lo, hi))(q, qlo, qhi)
-                return ids, dists, hops, router_chunks(chunks)
+                return ids, dists, hops, graph_counters(counts)
 
             self._hlo_fns[tier] = self._jit_hlo("graph", single)
             return self._device_run("graph", single)
@@ -455,17 +462,17 @@ class KHIService:
             def per_shard(di, off):
                 return _shard_search(di, off, n_shards, q, qlo, qhi,
                                      p, scorer, exact_scorer=exact)
-            gids, dists, hops, chunks = jax.vmap(per_shard)(skhi.di,
+            gids, dists, hops, counts = jax.vmap(per_shard)(skhi.di,
                                                             skhi.offsets)
             return _merge_topk(gids, dists, p.k) + (
-                jnp.max(hops, axis=0), router_chunks(chunks))
+                jnp.max(hops, axis=0), graph_counters(counts))
 
         self._hlo_fns[tier] = self._jit_hlo("graph", fanout)
         return self._device_run("graph", fanout)
 
     def _device_run(self, program: str, fn):
         """Host closure over a whole-batch program ``fn(index, q, lo, hi)``
-        that returns (ids, dists) or (ids, dists, hops, router chunks),
+        that returns (ids, dists) or (ids, dists, hops, graph_counters),
         under the ``khi.dispatch`` / ``khi.fetch`` profiler spans."""
         def run(q, lo, hi):
             with TraceAnnotation("khi.dispatch", program=program):
@@ -473,8 +480,9 @@ class KHIService:
                          jnp.asarray(hi))
             ids, dists, *graph = _fetch(program, out)
             if not graph:
-                return ids, dists, None, None, 0
-            return ids, dists, graph[0], None, int(graph[1])
+                return ids, dists, None, None, {}
+            return ids, dists, graph[0], None, dict(
+                zip(GRAPH_COUNTERS, graph[1].tolist()))
         return run
 
     def _jit_hlo(self, name: str, fn):
@@ -553,7 +561,7 @@ class KHIService:
             his = np.concatenate(
                 [his, np.full((pad, self.m), -np.inf, np.float32)])
         search = self._search if tier == 0 else self._get_search_fn(tier)
-        ids, dists, hops, plan, chunks = search(qs, los, his)
+        ids, dists, hops, plan, counters = search(qs, los, his)
         if self._stream is not None:
             # windowed merge (DESIGN.md §11): fold the per-shard delta
             # scans into the epoch results on the bucket-padded batch (the
@@ -575,7 +583,7 @@ class KHIService:
         routes = self._routes(plan, bucket, hops is None)[:b]
         if hops is not None:
             hops = hops[:b]
-            self._count_graph(plan, hops, routes, bucket, chunks)
+            self._count_graph(plan, hops, routes, bucket, counters)
         return ids[:b], dists[:b], hops, routes
 
     @staticmethod
@@ -593,13 +601,12 @@ class KHIService:
         return np.where(plan.use_scan, "scan", "graph")
 
     def _count_graph(self, plan, hops: np.ndarray, routes: np.ndarray,
-                     bucket: int, chunks: int) -> None:
+                     bucket: int, counters: dict) -> None:
         """Graph-program counters of one device batch, ``hops`` and
         ``routes`` of its real lanes: graph_lanes, graph_hops (scan and
         window lanes carry 0), graph_lane_steps — for each graph program
         run, the lanes it ran (pads included) times its largest hop
-        count — graph_runs, and router_chunks, the chunk iterations its
-        runs' level routers ran."""
+        count — graph_runs, and its runs' ``GRAPH_COUNTERS``."""
         self.stats["graph_lanes"] += int(
             np.isin(routes, ("graph", "mixed")).sum())
         self.stats["graph_hops"] += int(hops.sum())
@@ -607,7 +614,8 @@ class KHIService:
             (bucket, int(hops.max(initial=0)))]
         self.stats["graph_lane_steps"] += sum(n * h for n, h in runs)
         self.stats["graph_runs"] += len(runs)
-        self.stats["router_chunks"] += chunks
+        for name, v in counters.items():
+            self.stats[name] += v
 
     # -------------------------------------------------------------- serving
     def _answer(self, queries: np.ndarray, lo: np.ndarray,

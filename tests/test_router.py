@@ -31,7 +31,7 @@ def _route_all(index, preds, c_e=10):
         out["host_level"].append(qr.range_filter_level(index, pr, c_e))
         for name, fn in (("dev_dfs", rt.route_dfs),
                          ("dev_level", rt.route_level_sync)):
-            e, _card, _chunks = fn(di, qlo, qhi, p)
+            e, _card, _counts = fn(di, qlo, qhi, p)
             out[name].append([int(x) for x in np.asarray(e) if x >= 0])
     return out
 
@@ -78,14 +78,20 @@ def _boxes(preds):
             jnp.asarray(np.stack([pr.hi for pr in preds]), jnp.float32))
 
 
-def _chunks_expected(index, preds, F, C, H):
-    """(B, H) chunks per level the sweep owes each lane: ceil(w / C) of
-    the host twin's live frontier width w, 0 past its last level."""
+def _live_expected(index, preds, F, H):
+    """(B, H) the host twin's live frontier width per level for each
+    lane, 0 past its last level."""
     out = np.zeros((len(preds), H), np.int32)
     for i, pr in enumerate(preds):
-        w = np.asarray(qr.frontier_widths(index, pr, F))
-        out[i, :len(w)] = -(-w // C)
+        w = qr.frontier_widths(index, pr, F)
+        out[i, :len(w)] = w
     return out
+
+
+def _chunks_expected(index, preds, F, C, H):
+    """(B, H) chunks per level the sweep owes each lane: ceil(w / C) of
+    the host twin's live frontier width w."""
+    return -(-_live_expected(index, preds, F, H) // C)
 
 
 def _route_batch(di, p, preds, node_thr):
@@ -138,9 +144,12 @@ def test_level_router_chunk_widths(tiny_index, tiny_queries, monkeypatch,
     C = F if chunk is None else chunk
     assert F > 8 * 4
     monkeypatch.setattr(rt, "_CHUNK", 2 * F if chunk is None else chunk)
-    (ents, card, chunks), e_dfs, card_only, windows = _route_batch(
+    (ents, card, counts), e_dfs, card_only, windows = _route_batch(
         di, p, preds, node_thr=64)
+    chunks = counts.chunks
     np.testing.assert_array_equal(ents, e_dfs)
+    np.testing.assert_array_equal(counts.live,
+                                  _live_expected(tiny_index, preds, F, H))
     for i, pr in enumerate(preds):
         assert [int(x) for x in ents[i] if x >= 0] == \
             qr.range_filter_level(tiny_index, pr, p.c_e)
@@ -160,7 +169,7 @@ def test_level_router_mixed_lane_widths(tiny_index, tiny_data, tiny_queries,
     covered, no level below it) and a box cut just inside every
     attribute's range (the widest frontier). Each lane's entries and
     bound are those it gets routed alone; the batch's chunk loop runs to
-    its widest lane, which is what ``engine.router_chunks`` counts."""
+    its widest lane, which is what ``engine.level_lockstep`` counts."""
     _, attrs = tiny_data
     _, preds = tiny_queries
     m = tiny_index.m
@@ -177,8 +186,9 @@ def test_level_router_mixed_lane_widths(tiny_index, tiny_data, tiny_queries,
         eng.SearchParams(k=10, ef=32, c_e=10, c_n=16), di)
     F, H = p.frontier_cap, di.nbrs.shape[1]
     monkeypatch.setattr(rt, "_CHUNK", 3)
-    (ents, card, chunks), e_dfs, card_only, windows = _route_batch(
+    (ents, card, counts), e_dfs, card_only, windows = _route_batch(
         di, p, lanes, node_thr=64)
+    chunks = counts.chunks
     np.testing.assert_array_equal(ents, e_dfs)
     np.testing.assert_array_equal(card, card_only)
     exp = _chunks_expected(tiny_index, lanes, F, 3, H)
@@ -186,14 +196,14 @@ def test_level_router_mixed_lane_widths(tiny_index, tiny_data, tiny_queries,
     total = exp.sum(axis=1)
     assert total[0] == 1 and total[2] == 1      # the root's level alone
     assert total[3] > 2 * max(total[1], total[4]) > 0
-    assert int(eng.router_chunks(jnp.asarray(chunks))) == \
+    assert int(eng.level_lockstep(jnp.asarray(chunks))) == \
         exp.max(axis=0).sum()
     for i, pr in enumerate(lanes):
         e1, c1, ch1 = rt.route_level_sync(di, jnp.asarray(pr.lo),
                                           jnp.asarray(pr.hi), p)
         np.testing.assert_array_equal(ents[i], np.asarray(e1))
         assert card[i] == int(c1) == qr.estimate_cardinality(tiny_index, pr)
-        np.testing.assert_array_equal(chunks[i], np.asarray(ch1))
+        np.testing.assert_array_equal(chunks[i], np.asarray(ch1.chunks))
     assert (ents[0] < 0).all() and card[0] == 0
     assert card[2] == tiny_index.n
     _check_windows(tiny_index, lanes, windows, node_thr=64)
@@ -378,19 +388,21 @@ def test_frontier_cap_clamp_identical_across_chunks(tiny_index, tiny_queries,
     assert chunk < F < full.frontier_cap
     H = di.nbrs.shape[1]
     monkeypatch.setattr(rt, "_CHUNK", F)
-    (e_w, card_w, chunks_w), _, card_only_w, win_w = _route_batch(
+    (e_w, card_w, counts_w), _, card_only_w, win_w = _route_batch(
         di, p, preds, node_thr=64)
     monkeypatch.setattr(rt, "_CHUNK", chunk)
-    (e_c, card_c, chunks_c), _, card_only_c, win_c = _route_batch(
+    (e_c, card_c, counts_c), _, card_only_c, win_c = _route_batch(
         di, p, preds, node_thr=64)
     np.testing.assert_array_equal(e_c, e_w)
     np.testing.assert_array_equal(card_c, card_w)
     np.testing.assert_array_equal(card_only_c, card_only_w)
     for a, b in zip(win_c, win_w):
         np.testing.assert_array_equal(a, b)
-    for got, C in ((chunks_w, F), (chunks_c, chunk)):
+    for got, C in ((counts_w, F), (counts_c, chunk)):
         np.testing.assert_array_equal(
-            got, _chunks_expected(tiny_index, preds, F, C, H))
+            got.chunks, _chunks_expected(tiny_index, preds, F, C, H))
+        np.testing.assert_array_equal(
+            got.live, _live_expected(tiny_index, preds, F, H))
     twin = [qr._level_sweep(tiny_index, pr, F) for pr in preds]
     assert any(max(w) == F for w, _ in twin)             # the clamp bites
     np.testing.assert_array_equal(card_c, [c for _, c in twin])

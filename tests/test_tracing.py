@@ -84,14 +84,20 @@ def test_spans_nest_inside_search_in_order(tiny_index, batch, tmp_path):
 
 def test_graph_program_carries_phase_scopes(tiny_index, batch):
     """The compiled graph program names its phases in its op_name
-    metadata (jax.named_scope; under vmap as ``vmap(khi_router)``)."""
+    metadata (jax.named_scope; under vmap as ``vmap(khi_router)``), and
+    the router's entry scans apart from its sweep."""
     svc = _service(tiny_index, batch[3], cache_size=0)
     names = re.findall(r'op_name="([^"]*)"', svc.compiled_hlo(B)["graph"])
     router = [n for n in names if "khi_router" in n]
     loop = [n for n in names if "khi_hop_loop" in n]
-    assert router and loop
+    scans = [n for n in names if "khi_entry_scan" in n]
+    assert router and loop and scans
     assert not any("khi_hop_loop" in n for n in router)
     assert any("/while/body" in n for n in loop)
+    # the scans nest in the router's scope; the sweep's ops do not
+    assert any("khi_router" in n for n in scans)
+    assert not any("khi_hop_loop" in n for n in scans)
+    assert any("khi_entry_scan" not in n for n in router)
 
 
 @pytest.mark.parametrize("strategy", ["auto", "graph"])
@@ -148,10 +154,11 @@ def test_router_chunks_counter(tiny_index, batch, strategy):
                                                                  hi[i])))
                  for i in graph)
     H = eng.device_put_index(tiny_index).nbrs.shape[1]
-    assert plan.router_chunks == levels <= H * len(plan.graph_runs)
+    assert plan.counters["router_chunks"] == levels <= \
+        H * len(plan.graph_runs)
     svc.search(Q, lo, hi)
     s = svc.snapshot()["router_chunks"]
-    assert s == plan.router_chunks
+    assert s == plan.counters["router_chunks"]
     svc.search(Q, lo, hi)                          # every lane a hit
     assert svc.snapshot()["router_chunks"] == s
     if strategy == "auto":
@@ -175,4 +182,4 @@ def test_snapshot_reports_counters_not_device_qps(tiny_index, batch):
     snap = _service(tiny_index, batch[3]).snapshot()
     assert not {"device_qps", "device_seconds", "device_queries"} & set(snap)
     assert {"graph_lanes", "graph_hops", "graph_lane_steps",
-            "router_chunks"} <= set(snap)
+            *eng.GRAPH_COUNTERS} <= set(snap)
